@@ -31,11 +31,14 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
 
 SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                    "src")
+if SRC not in sys.path:          # standalone `python benchmarks/...` use
+    sys.path.insert(0, SRC)
+
+from repro.fake_devices import CPU_ROWS_NOTE, run_on_cpu_devices  # noqa: E402
 
 _SNIPPET = r"""
 import json, time
@@ -98,13 +101,10 @@ print(json.dumps({{"B": B, "n": n, "devices": len(jax.devices()),
 
 def run(B: int = 64, n: int = 128, devices: int = 2, timeout: int = 900,
         compaction: bool = False) -> dict:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
-    env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
-    out = subprocess.run(
-        [sys.executable, "-c",
-         _SNIPPET.format(B=B, n=n, compaction=compaction)],
-        capture_output=True, text=True, env=env, timeout=timeout)
+    print(CPU_ROWS_NOTE)
+    out = run_on_cpu_devices(
+        _SNIPPET.format(B=B, n=n, compaction=compaction), devices,
+        timeout=timeout)
     if out.returncode != 0:
         raise RuntimeError(f"bench_batch failed:\n{out.stderr[-2000:]}")
     return json.loads(out.stdout.strip().splitlines()[-1])

@@ -25,7 +25,6 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
 import time
 
@@ -33,6 +32,8 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 SRC = os.path.join(_ROOT, "src")
 if SRC not in sys.path:          # standalone `python benchmarks/...` use
     sys.path.insert(0, SRC)
+
+from repro.fake_devices import CPU_ROWS_NOTE, run_on_cpu_devices  # noqa: E402
 
 # per-device replicated O(n) state, bytes per padded slot: u (f32) +
 # alive (bool) + sizes (f32) + chain (i32) + merges (4×f32) ≈ 29 B —
@@ -46,12 +47,7 @@ def _model_bytes(n_pad: int, d: int, p: int) -> int:
 
 
 def _run_probe(snippet: str, p: int, timeout: int = 560):
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={p}"
-    env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
-    out = subprocess.run([sys.executable, "-c", snippet],
-                         capture_output=True, text=True, env=env,
-                         timeout=timeout)
+    out = run_on_cpu_devices(snippet, p, timeout=timeout)
     if out.returncode != 0:
         raise RuntimeError(
             f"probe failed (p={p}):\n{out.stderr[-2000:]}"
@@ -141,6 +137,7 @@ print(json.dumps({{"p": p, "n": n, "n_pad": n_pad, "d": d,
 
 
 def main(*, smoke: bool = False, paper: bool = False):
+    print(CPU_ROWS_NOTE)
     d = 16
     if smoke:
         eq_ns, eq_ps = 256, (2,)

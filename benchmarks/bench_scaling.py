@@ -17,11 +17,14 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
 
 SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                    "src")
+if SRC not in sys.path:          # standalone `python benchmarks/...` use
+    sys.path.insert(0, SRC)
+
+from repro.fake_devices import CPU_ROWS_NOTE, run_on_cpu_devices  # noqa: E402
 
 _SNIPPET = r"""
 import json, time
@@ -67,13 +70,10 @@ print(json.dumps({{"p": p, "wall_s": wall,
 
 def run(n: int = 1968, procs=(1, 2, 4, 8, 16), timeout: int = 900):
     rows = []
+    print(CPU_ROWS_NOTE)
     for p in procs:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={p}"
-        env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
-        out = subprocess.run(
-            [sys.executable, "-c", _SNIPPET.format(n=n, p=p)],
-            capture_output=True, text=True, env=env, timeout=timeout)
+        out = run_on_cpu_devices(_SNIPPET.format(n=n, p=p), p,
+                                 timeout=timeout)
         if out.returncode != 0:
             raise RuntimeError(f"p={p} failed:\n{out.stderr[-2000:]}")
         rows.append(json.loads(out.stdout.strip().splitlines()[-1]))

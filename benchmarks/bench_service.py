@@ -349,6 +349,11 @@ def _overload_gates(report) -> list[str]:
                 "untyped error — overload must resolve as typed "
                 "ServiceOverloaded/DeadlineExceeded, never a crash"
             )
+        if p.n_unresolved:
+            violations.append(
+                f"at {p.multiple:g}x, {p.n_unresolved} requests were still "
+                "pending after the flush — every future must resolve"
+            )
     half = report.point(0.5)
     if half.shed_rate > 0.05:
         violations.append(

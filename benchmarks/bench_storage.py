@@ -5,11 +5,14 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
 
 SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                    "src")
+if SRC not in sys.path:          # standalone `python benchmarks/...` use
+    sys.path.insert(0, SRC)
+
+from repro.fake_devices import CPU_ROWS_NOTE, run_on_cpu_devices  # noqa: E402
 
 _SNIPPET = r"""
 import json
@@ -36,14 +39,9 @@ def run(n: int = 1968, procs=(1, 2, 4, 8, 16)):
     configuration can't silently erase the whole sweep.
     """
     rows, failures = [], []
+    print(CPU_ROWS_NOTE)
     for p in procs:
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={p}"
-        env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
-        out = subprocess.run([sys.executable, "-c",
-                              _SNIPPET.format(n=n, p=p)],
-                             capture_output=True, text=True, env=env,
-                             timeout=300)
+        out = run_on_cpu_devices(_SNIPPET.format(n=n, p=p), p, timeout=300)
         if out.returncode != 0:
             sys.stderr.write(
                 f"bench_storage: p={p} probe failed "

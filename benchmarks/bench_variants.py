@@ -6,11 +6,14 @@ from __future__ import annotations
 
 import json
 import os
-import subprocess
 import sys
 
 SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
                    "src")
+if SRC not in sys.path:          # standalone `python benchmarks/...` use
+    sys.path.insert(0, SRC)
+
+from repro.fake_devices import CPU_ROWS_NOTE, run_on_cpu_devices  # noqa: E402
 
 _SNIPPET = r"""
 import json, time, math
@@ -51,15 +54,12 @@ print(json.dumps({{"variant": variant, "wall_s": wall,
 
 def run(n: int = 768, p: int = 4, compaction: bool = False):
     rows = []
+    print(CPU_ROWS_NOTE)
     for variant in ("baseline", "rowmin", "lazy"):
-        env = dict(os.environ)
-        env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={p}"
-        env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
-        out = subprocess.run(
-            [sys.executable, "-c",
-             _SNIPPET.format(n=n, p=p, variant=variant,
-                             compaction=compaction)],
-            capture_output=True, text=True, env=env, timeout=900)
+        out = run_on_cpu_devices(
+            _SNIPPET.format(n=n, p=p, variant=variant,
+                            compaction=compaction),
+            p, timeout=900)
         if out.returncode != 0:
             raise RuntimeError(out.stderr[-2000:])
         rows.append(json.loads(out.stdout.strip().splitlines()[-1]))

@@ -138,6 +138,9 @@ def main() -> None:
                          "regression (default 0.30 = +30%%)")
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         bench_batch,
         bench_distributed,

@@ -63,15 +63,16 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import pvary, shard_map
 from repro.core.engine import (
     AXIS,
     VARIANTS,
     LWResult,
     _first_where,
     make_sharded_body,
+    match_vma,
     resolve_compaction,
     resolve_n_steps,
     symmetrize,
@@ -267,8 +268,6 @@ def distributed_pairwise(
     "as the data files were read in from disk they were sent to the
     processors").
     """
-    from repro.core import distance as dist
-
     mesh = require_ring_mesh(mesh)
     p = mesh.devices.size
     X = jnp.asarray(X, jnp.float32)
@@ -276,6 +275,16 @@ def distributed_pairwise(
     n_pad = pad_to_mesh(n, p)
     if n_pad != n:
         X = jnp.concatenate([X, jnp.zeros((n_pad - n,) + X.shape[1:], X.dtype)], 0)
+
+    Xs = jax.device_put(X, NamedSharding(mesh, P(AXIS, *([None] * (X.ndim - 1)))))
+    D = pairwise_program(kind, mesh, X.ndim)(Xs)
+    return D[:n, :n] if n_pad != n else D
+
+
+def pairwise_program(kind: str, mesh: Mesh, ndim: int):
+    """The jitted row-sharded build behind :func:`distributed_pairwise`
+    for ``ndim``-dimensional input (2: points, 3: conformations)."""
+    from repro.core import distance as dist
 
     def body(X_local):
         X_full = jax.lax.all_gather(X_local, AXIS, tiled=True)
@@ -292,17 +301,14 @@ def distributed_pairwise(
             return rows
         raise ValueError(f"unknown distance kind {kind!r}")
 
-    Xs = jax.device_put(X, NamedSharding(mesh, P(AXIS, *([None] * (X.ndim - 1)))))
-    fn = jax.jit(
+    return jax.jit(
         shard_map(
             body,
             mesh=mesh,
-            in_specs=(P(AXIS, *([None] * (X.ndim - 1))),),
+            in_specs=(P(AXIS, *([None] * (ndim - 1))),),
             out_specs=P(AXIS, None),
         )
     )
-    D = fn(Xs)
-    return D[:n, :n] if n_pad != n else D
 
 
 # ---------------------------------------------------------------------------
@@ -355,12 +361,6 @@ def _make_sharded_chain_body(
         ks = jnp.arange(n_pad)
         shard_ids = jnp.arange(p)
         iter_cap = jnp.int32(4 * n_pad + 8)
-        (u0, alive0, sizes0, chain0, chain_len0, merges0, n_merges0,
-         iters0, target) = (
-            pvary(x, AXIS) for x in
-            (u0, alive0, sizes0, chain0, chain_len0, merges0, n_merges0,
-             iters0, target)
-        )
 
         def owner_bcast(W_loc, slot):
             """Summary point of *slot*, contributed by its owner — O(d)."""
@@ -478,13 +478,15 @@ def _make_sharded_chain_body(
                 )
 
             s = jax.lax.cond(prev_hit, do_merge, do_push, s)
-            return s._replace(iters=s.iters + 1)
+            return match_vma(s._replace(iters=s.iters + 1))
 
-        state = NNState(
+        # the carry mixes the shard-varying W block and replicated O(n)
+        # state; the loop types everything varying, the epilogue reduces
+        state = match_vma(NNState(
             rep=(W_local, u0), alive=alive0, sizes=sizes0, chain=chain0,
             chain_len=chain_len0, merges=merges0, n_merges=n_merges0,
             iters=iters0,
-        )
+        ))
         out = jax.lax.while_loop(cond, trip, state)
         # replicated outputs are bitwise equal across shards by
         # construction (collective results are); the pmax epilogue

@@ -71,7 +71,6 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.compat import pvary
 from repro.core.linkage import update_row
 
 #: Mesh axis name of the paper's 1-D processor ring (shared by every
@@ -92,6 +91,29 @@ MIN_STAGE_N = 32
 
 _F32 = jnp.float32
 _INF = jnp.float32(jnp.inf)
+
+
+def match_vma(tree):
+    """Make every leaf vary over the union of the leaves' manual axes.
+
+    Inside ``shard_map`` a loop carry must keep its type across a trip:
+    a leaf that starts replicated (a zero merge list, a fresh cache) but
+    comes back shard-varying (written from a shard's block) fails the
+    ``while_loop``/``fori_loop`` carry check.  Loops apply this to the
+    initial carry and to each trip's result, so both sides agree.
+    Outside ``shard_map`` every leaf's ``vma`` is empty and this is the
+    identity.
+    """
+    leaves = jax.tree.leaves(tree)
+    axes = frozenset().union(*(jax.typeof(x).vma for x in leaves))
+    if not axes:
+        return tree
+
+    def cast(x):
+        missing = tuple(sorted(axes - jax.typeof(x).vma))
+        return jax.lax.pcast(x, missing, to="varying") if missing else x
+
+    return jax.tree.map(cast, tree)
 
 
 # ---------------------------------------------------------------------------
@@ -434,8 +456,12 @@ def run_merge_loop(
     """
     if n_steps <= start:   # stop_at_k >= n: nothing to merge, nothing to trace
         return state
-    step = make_step(ops)
-    state = ops.seed(state)
+    make = make_step(ops)
+
+    def step(s, t=None):
+        return match_vma(make(s, t))
+
+    state = match_vma(ops.seed(state))
     if distance_threshold is None:
         return jax.lax.fori_loop(start, n_steps, lambda t, s: step(s, t), state)
     thr = jnp.asarray(distance_threshold, _F32)
@@ -546,9 +572,11 @@ def _drain_cache(rmin, rarg, dirty, rescan_rows, K: int):
         sel = dirty[picks]
         rmin = rmin.at[picks].set(jnp.where(sel, rm, rmin[picks]))
         rarg = rarg.at[picks].set(jnp.where(sel, ra, rarg[picks]))
-        return rmin, rarg, dirty.at[picks].set(False)
+        return match_vma((rmin, rarg, dirty.at[picks].set(False)))
 
-    rmin, rarg, _ = jax.lax.while_loop(cond, body, (rmin, rarg, dirty))
+    rmin, rarg, _ = jax.lax.while_loop(
+        cond, body, match_vma((rmin, rarg, dirty))
+    )
     return rmin, rarg
 
 
@@ -1116,13 +1144,14 @@ def make_sharded_body(
             return D_new, live, sizes_new, remap[pc]
 
         # the carry mixes shard-varying (D_local, cache) and replicated
-        # values; mark everything varying and reduce back at the end.
+        # values; the loop marks everything varying (match_vma) and the
+        # epilogue reduces back.
         state = LWState(
             D=D_local,
-            alive=pvary(alive0, AXIS),
-            sizes=pvary(sizes0.astype(_F32), AXIS),
-            merges=pvary(jnp.zeros((n_steps, 4), _F32), AXIS),
-            n_merges=pvary(jnp.zeros((), jnp.int32), AXIS),
+            alive=alive0,
+            sizes=sizes0.astype(_F32),
+            merges=jnp.zeros((n_steps, 4), _F32),
+            n_merges=jnp.zeros((), jnp.int32),
             cand=(jnp.zeros((), jnp.int32), jnp.zeros((), jnp.int32),
                   jnp.zeros((), _F32)),
             cache=_dense_cache(rows0, variant),   # shard-local row cache
@@ -1130,7 +1159,7 @@ def make_sharded_body(
         state = staged_merge_loop(
             stages,
             state,
-            pvary(jnp.arange(n_pad0, dtype=jnp.int32), AXIS),
+            jnp.arange(n_pad0, dtype=jnp.int32),
             threshold if with_threshold else None,
             ops_for=lambda size: build_ops(size // p, size),
             compact=compact_sharded,

@@ -642,7 +642,7 @@ def _points_nnchain_ops(
     runs through the shared row-build dispatch
     (:func:`repro.kernels.pairwise.row_sq_euclidean`) — one jnp pass by
     default, or tile-by-tile through the Pallas row-vs-points kernel
-    when ``use_pallas`` (TPU; validated in interpret mode on CPU).
+    when ``use_pallas`` (compiled on a TPU, interpreted elsewhere).
     Everything else is O(n) epilogue.
     """
     del n  # summaries broadcast; kept for signature stability

@@ -32,6 +32,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from repro.core.linkage import METHODS, coefficients
+from repro.kernels.minscan import row_min_args, slab_rows
 
 _LANES = 128
 
@@ -46,17 +47,17 @@ def _make_kernel(method: str):
         d_ref,          # (bm, n)  this row slab of D
         dki_col_ref,    # (1, n)   fetched column i (== row i, symmetric)
         dkj_col_ref,    # (1, n)   fetched column j
-        dki_row_ref,    # (1, bm)  slab-rows slice of column i
-        dkj_row_ref,    # (1, bm)  slab-rows slice of column j
+        dki_row_ref,    # (bm, 1)  slab-rows slice of column i
+        dkj_row_ref,    # (bm, 1)  slab-rows slice of column j
         sizes_col_ref,  # (1, n)   pre-merge cluster sizes
-        sizes_row_ref,  # (1, bm)  slab-rows slice of sizes
+        sizes_row_ref,  # (bm, 1)  slab-rows slice of sizes
         alive_col_ref,  # (1, n)   pre-merge liveness (float)
-        alive_row_ref,  # (1, bm)  slab-rows slice of liveness
+        alive_row_ref,  # (bm, 1)  slab-rows slice of liveness
         scal_ref,       # (1, lanes) float32: d_ij, n_i, n_j
         idx_ref,        # (1, lanes) int32:   i, j
         out_ref,        # (bm, n)  new slab
-        rmin_ref,       # (1, bm)  per-row min of the new masked matrix
-        rarg_ref,       # (1, bm)  per-row first-col argmin
+        rmin_ref,       # (bm, 1)  per-row min of the new masked matrix
+        rarg_ref,       # (bm, 1)  per-row first-col argmin
     ):
         s = pl.program_id(0)
         d = d_ref[...]
@@ -68,7 +69,7 @@ def _make_kernel(method: str):
         j = idx_ref[0, 1]
 
         cols = jax.lax.broadcasted_iota(jnp.int32, (1, n), 1)
-        rows = s * bm + jax.lax.broadcasted_iota(jnp.int32, (1, bm), 1)
+        rows = s * bm + jax.lax.broadcasted_iota(jnp.int32, (bm, 1), 0)
 
         # the merged row, full length (paper step 6b — lw_update's fusion)
         alive_col = alive_col_ref[...] != 0
@@ -82,26 +83,22 @@ def _make_kernel(method: str):
         keep_row = alive_row & (rows != i) & (rows != j)
         new_rows = _lw(method, dki_row_ref[...], dkj_row_ref[...], d_ij,
                        n_i, n_j, sizes_row_ref[...])
-        new_rows = jnp.where(keep_row, new_rows, 0.0)      # (1, bm)
+        new_rows = jnp.where(keep_row, new_rows, 0.0)      # (bm, 1)
 
         row_g = s * bm + jax.lax.broadcasted_iota(jnp.int32, (bm, n), 0)
         col_g = jax.lax.broadcasted_iota(jnp.int32, (bm, n), 1)
-        out = jnp.where(col_g == i, new_rows.reshape(bm, 1), d)
+        out = jnp.where(col_g == i, new_rows, d)
         out = jnp.where(row_g == i, new_full, out)         # row/col j: garbage
         out_ref[...] = out
 
         # next step's row minima over the just-written slab (step 1 of the
         # NEXT iteration), masked with the post-merge liveness (j dead)
-        live_r = (alive_row & (rows != j)).reshape(bm, 1)
+        live_r = alive_row & (rows != j)
         live_c = alive_col & (cols != j)
         valid = live_r & live_c & (row_g != col_g)
-        dm = jnp.where(valid, out, jnp.inf)
-        rmin = jnp.min(dm, axis=1)                         # (bm,)
-        rarg = jnp.min(
-            jnp.where(dm == rmin[:, None], col_g, n), axis=1
-        )
-        rmin_ref[...] = rmin.reshape(1, bm)
-        rarg_ref[...] = rarg.reshape(1, bm).astype(jnp.int32)
+        rmin, rarg = row_min_args(jnp.where(valid, out, jnp.inf), col_g, n)
+        rmin_ref[...] = rmin
+        rarg_ref[...] = rarg
 
     return kernel
 
@@ -124,7 +121,8 @@ def lw_step_pallas(
 ):
     """One fused merge step: commit merge ``(i, j)`` and return the next
     row minima.  Requires square lane-aligned ``D`` with
-    ``n % block_m == 0``.
+    ``n % block_m == 0``; the slab height then shrinks to fit VMEM
+    (:func:`repro.kernels.minscan.slab_rows`).
 
     D: ``(n, n)`` float32 (garbage representation);
     d_ki, d_kj: ``(n,)`` fetched columns; sizes: ``(n,)`` pre-merge sizes;
@@ -137,6 +135,7 @@ def lw_step_pallas(
         raise ValueError(f"unknown linkage method {method!r}")
     n = D.shape[0]
     assert D.shape == (n, n) and n % block_m == 0, (D.shape, block_m)
+    block_m = slab_rows(n, block_m)
 
     scal = jnp.zeros((1, _LANES), jnp.float32)
     scal = scal.at[0, 0].set(d_ij).at[0, 1].set(n_i).at[0, 2].set(n_j)
@@ -144,12 +143,15 @@ def lw_step_pallas(
     idx = idx.at[0, 0].set(i).at[0, 1].set(j)
 
     col_spec = pl.BlockSpec((1, n), lambda s: (0, 0))
-    row_spec = pl.BlockSpec((1, block_m), lambda s: (0, s))
+    row_spec = pl.BlockSpec((block_m, 1), lambda s: (s, 0))
     slab_spec = pl.BlockSpec((block_m, n), lambda s: (s, 0))
     scal_spec = pl.BlockSpec((1, _LANES), lambda s: (0, 0))
 
-    def as_row(a):
+    def as_row(a):      # lane-dense (1, n): the full-length operands
         return a.reshape(1, n).astype(jnp.float32)
+
+    def as_col(a):      # (n, 1): sliced per slab as (block_m, 1) blocks
+        return a.reshape(n, 1).astype(jnp.float32)
 
     D_new, rmin, rarg = pl.pallas_call(
         _make_kernel(method),
@@ -161,22 +163,18 @@ def lw_step_pallas(
             col_spec, row_spec,
             scal_spec, scal_spec,
         ],
-        out_specs=[
-            slab_spec,
-            pl.BlockSpec((1, block_m), lambda s: (0, s)),
-            pl.BlockSpec((1, block_m), lambda s: (0, s)),
-        ],
+        out_specs=[slab_spec, row_spec, row_spec],
         out_shape=[
             jax.ShapeDtypeStruct((n, n), jnp.float32),
-            jax.ShapeDtypeStruct((1, n), jnp.float32),
-            jax.ShapeDtypeStruct((1, n), jnp.int32),
+            jax.ShapeDtypeStruct((n, 1), jnp.float32),
+            jax.ShapeDtypeStruct((n, 1), jnp.int32),
         ],
         interpret=interpret,
     )(
         D,
-        as_row(d_ki), as_row(d_kj), as_row(d_ki), as_row(d_kj),
-        as_row(sizes), as_row(sizes),
-        as_row(alive), as_row(alive),
+        as_row(d_ki), as_row(d_kj), as_col(d_ki), as_col(d_kj),
+        as_row(sizes), as_col(sizes),
+        as_row(alive), as_col(alive),
         scal, idx,
     )
     return D_new, rmin.reshape(n), rarg.reshape(n)

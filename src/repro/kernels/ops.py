@@ -1,8 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-Handles padding/alignment (TPU lane multiples), selects interpret mode
-automatically on CPU (the kernels are *targeted* at TPU and *validated*
-in interpret mode here), and provides ``lance_williams_kernelized`` —
+Handles padding/alignment (TPU lane multiples), compiles the kernels with
+Mosaic on a TPU and selects interpret mode on any other backend, and
+provides ``lance_williams_kernelized`` —
 the unified merge loop (:mod:`repro.core.engine`) composed with the
 Pallas min-scan argmin op and the Pallas ``lw_update`` update op.  The
 batched variant is the same composition under ``vmap``: the

@@ -21,6 +21,7 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _pairwise_kernel(x_ref, y_ref, out_ref):
@@ -31,6 +32,7 @@ def _pairwise_kernel(x_ref, y_ref, out_ref):
     g = jax.lax.dot_general(
         x, y,
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,    # float32 passes, not bf16
         preferred_element_type=jnp.float32,
     )                                           # (bm, bn) on the MXU
     d = xx[:, None] + yy[None, :] - 2.0 * g
@@ -120,6 +122,7 @@ def _row_kernel(x_ref, y_ref, out_ref):
     g = jax.lax.dot_general(
         x, y,
         dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,    # float32 passes, not bf16
         preferred_element_type=jnp.float32,
     )                                           # (1, bn) on the MXU
     out_ref[...] = jnp.maximum(xx + yy[None, :] - 2.0 * g, 0.0)
@@ -145,6 +148,17 @@ def row_sq_euclidean_pallas(
     assert x.shape == (d,) and m % block_n == 0 and d % 128 == 0, (
         x.shape, Y.shape, block_n,
     )
+    from repro.core.engine import match_vma
+
+    # inside shard_map the kernel's operands and output must all vary
+    # over the same manual axes (the tip is replicated, the block is not)
+    x, Y = match_vma((x, Y))
+    vma = jax.typeof(Y).vma
+    if interpret and vma:
+        # the generic interpreter re-evaluates the kernel with replicated
+        # literals, which shard_map's vma check rejects; the TPU
+        # interpreter types them correctly
+        interpret = pltpu.InterpretParams()
     out = pl.pallas_call(
         _row_kernel,
         grid=(m // block_n,),
@@ -153,7 +167,7 @@ def row_sq_euclidean_pallas(
             pl.BlockSpec((block_n, d), lambda i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((1, block_n), lambda i: (0, i)),
-        out_shape=jax.ShapeDtypeStruct((1, m), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((1, m), jnp.float32, vma=vma),
         interpret=interpret,
     )(x[None, :], Y)
     return out[0]

@@ -12,6 +12,7 @@ import time
 import jax
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.core import cluster
 from repro.core.distributed import distributed_pairwise, make_cluster_mesh
 from repro.data.synthetic import conformations, gaussian_mixture
@@ -30,6 +31,7 @@ def main() -> None:
                     choices=("baseline", "rowmin", "lazy"))
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     ndev = len(jax.devices())
     print(f"[cluster] devices={ndev} n={args.n} method={args.method} "

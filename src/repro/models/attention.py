@@ -34,9 +34,9 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.distributed.sharding import current_mesh, current_rules, logical_constraint as lc
 from repro.models.common import ParamSpec, rms_norm
 

@@ -21,9 +21,9 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.compat import shard_map
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import current_mesh, current_rules
 from repro.models.common import ACTIVATIONS, ParamSpec

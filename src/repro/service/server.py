@@ -41,6 +41,7 @@ from dataclasses import dataclass
 
 import numpy as np
 
+from repro.compile_cache import enable_compile_cache
 from repro.obs import PeriodicDumper, Tracer, dump_json, prometheus_text
 from repro.service.batcher import ClusteringService, MetricsSnapshot, ServiceConfig
 from repro.service.cache import engine_jit_cache_size
@@ -212,6 +213,7 @@ class OverloadPoint:
     n_shed: int                 # typed ServiceOverloaded resolutions
     n_expired: int              # typed DeadlineExceeded resolutions
     n_failed: int               # anything else
+    n_unresolved: int           # still pending when the flush timed out
     shed_by_lane: tuple[int, ...]       # shed + expired, per lane
     p50_admitted_ms: float
     p99_admitted_ms: float      # latency percentiles of SERVED requests
@@ -238,6 +240,36 @@ class OverloadReport:
             if p.multiple == multiple:
                 return p
         raise KeyError(f"no sweep point at {multiple}x")
+
+
+def tally_futures(laned: list[tuple[int, Future]], n_lanes: int) -> dict:
+    """Classify ``(lane, future)`` pairs by their typed resolution.
+
+    Served, shed (:class:`ServiceOverloaded`), expired
+    (:class:`DeadlineExceeded`) and failed futures are counted from
+    their result; a future that has not resolved counts as unresolved,
+    never as served.  Returns the matching :class:`OverloadPoint` fields.
+    """
+    n_ok = n_shed = n_expired = n_failed = n_unresolved = 0
+    shed_by_lane = [0] * n_lanes
+    for lane, fut in laned:
+        if not fut.done():
+            n_unresolved += 1
+            continue
+        exc = fut.exception()
+        if exc is None:
+            n_ok += 1
+        elif isinstance(exc, ServiceOverloaded):
+            n_shed += 1
+            shed_by_lane[lane] += 1
+        elif isinstance(exc, DeadlineExceeded):
+            n_expired += 1
+            shed_by_lane[lane] += 1
+        else:
+            n_failed += 1
+    return dict(n_ok=n_ok, n_shed=n_shed, n_expired=n_expired,
+                n_failed=n_failed, n_unresolved=n_unresolved,
+                shed_by_lane=tuple(shed_by_lane))
 
 
 def overload_sweep(
@@ -303,30 +335,13 @@ def overload_sweep(
             service.flush(timeout=120.0)
             elapsed = time.perf_counter() - t0
             snap = service.metrics.snapshot(service.cache)
-        n_ok = n_shed = n_expired = n_failed = 0
-        shed_by_lane = [0] * config.n_lanes
-        for lane, fut in laned:
-            exc = fut.exception() if fut.done() else None
-            if not fut.done() or exc is None:
-                n_ok += 1
-            elif isinstance(exc, ServiceOverloaded):
-                n_shed += 1
-                shed_by_lane[lane] += 1
-            elif isinstance(exc, DeadlineExceeded):
-                n_expired += 1
-                shed_by_lane[lane] += 1
-            else:
-                n_failed += 1
+        tally = tally_futures(laned, config.n_lanes)
         points.append(OverloadPoint(
             multiple=multiple,
             offered_rps=len(laned) / elapsed if elapsed else 0.0,
             elapsed_s=elapsed,
             n_submitted=len(laned),
-            n_ok=n_ok,
-            n_shed=n_shed,
-            n_expired=n_expired,
-            n_failed=n_failed,
-            shed_by_lane=tuple(shed_by_lane),
+            **tally,
             p50_admitted_ms=snap.p50_ms,
             p99_admitted_ms=snap.p99_ms,
         ))
@@ -505,6 +520,7 @@ def main(argv: list[str] | None = None) -> "LoadReport | OverloadReport":
     ap.add_argument("--multiples", default="0.5,1,2,4",
                     help="capacity multiples for --overload")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     if args.overload:
         report = overload_sweep(

@@ -1,18 +1,15 @@
 """Shared test fixtures.  NOTE: no XLA_FLAGS here — unit tests must see the
-real single-device CPU; multi-device tests spawn subprocesses with
-``--xla_force_host_platform_device_count`` themselves."""
+real single-device CPU; multi-device tests spawn subprocesses pinned to the
+CPU (``JAX_PLATFORMS=cpu``) with ``--xla_force_host_platform_device_count``
+themselves (:mod:`repro.fake_devices`)."""
 
-import os
 import signal
-import subprocess
-import sys
 import threading
 
 import numpy as np
 import pytest
 
-REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-SRC = os.path.join(REPO, "src")
+from repro.fake_devices import run_on_cpu_devices
 
 
 def pytest_addoption(parser):
@@ -53,11 +50,7 @@ def pytest_runtest_call(item):
 
 def run_with_devices(code: str, n_devices: int = 8, timeout: int = 560) -> str:
     """Run a python snippet in a subprocess with n fake CPU devices."""
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={n_devices}"
-    env["PYTHONPATH"] = SRC + os.pathsep + env.get("PYTHONPATH", "")
-    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, env=env, timeout=timeout)
+    out = run_on_cpu_devices(code, n_devices, timeout=timeout)
     assert out.returncode == 0, f"subprocess failed:\n{out.stdout}\n{out.stderr}"
     return out.stdout
 

@@ -61,6 +61,34 @@ def test_rmsd_detects_reflection(rng):
     assert float(kabsch_rmsd(A, B)) > 0.1
 
 
+def _svd_rmsd(A, B):
+    """Float64 Kabsch through numpy's SVD — the reference for the
+    Jacobi singular values inside :func:`kabsch_rmsd`."""
+    A = A - A.mean(0)
+    B = B - B.mean(0)
+    U, S, Vt = np.linalg.svd(A.T @ B)
+    corr = S[0] + S[1] + np.sign(np.linalg.det(Vt.T @ U.T)) * S[2]
+    return np.sqrt(max((np.sum(A * A) + np.sum(B * B) - 2 * corr) / len(A), 0))
+
+
+def test_rmsd_matches_float64_svd(rng):
+    """Jacobi singular values agree with a float64 SVD to the float32
+    floor of the RMSD formula — on generic pairs, mirror images and
+    planar conformations (a zero singular value)."""
+    from repro.data.synthetic import conformations
+
+    confs, _ = conformations(0, 24, 24)
+    confs = confs.astype(np.float64)
+    planar = confs[0].copy()
+    planar[:, 2] = 0.0
+    pairs = [(confs[a], confs[b]) for a in range(8) for b in range(8, 24)]
+    pairs += [(confs[0], confs[0] * np.array([1.0, 1.0, -1.0])),
+              (planar, planar @ _rand_rot(rng).T)]
+    for A, B in pairs:
+        got = float(kabsch_rmsd(A.astype(np.float32), B.astype(np.float32)))
+        assert abs(got - _svd_rmsd(A, B)) < 1e-4
+
+
 def test_rmsd_scales_with_noise(rng):
     A = rng.normal(size=(30, 3)).astype(np.float32)
     small = A + rng.normal(size=A.shape).astype(np.float32) * 0.01

@@ -440,3 +440,34 @@ def test_counters_exported_through_registry(rng):
     finally:
         gate.set()
         svc.close()
+
+
+# ---------------------------------------------------------------------------
+# overload sweep accounting
+# ---------------------------------------------------------------------------
+
+
+def test_overload_tally_counts_pending_as_unresolved():
+    from concurrent.futures import Future
+
+    from repro.service.server import tally_futures
+
+    def resolved(result=None, exc=None):
+        fut = Future()
+        if exc is None:
+            fut.set_result(result)
+        else:
+            fut.set_exception(exc)
+        return fut
+
+    laned = [
+        (0, resolved("ok")),
+        (1, resolved(exc=ServiceOverloaded("full"))),
+        (1, resolved(exc=DeadlineExceeded("late"))),
+        (0, resolved(exc=RuntimeError("boom"))),
+        (1, Future()),                       # never resolved
+        (0, Future()),
+    ]
+    tally = tally_futures(laned, n_lanes=2)
+    assert tally == dict(n_ok=1, n_shed=1, n_expired=1, n_failed=1,
+                         n_unresolved=2, shed_by_lane=(0, 2))
