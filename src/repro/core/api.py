@@ -22,7 +22,8 @@ from __future__ import annotations
 
 import warnings
 from dataclasses import dataclass, field
-from typing import Literal, Sequence
+from functools import partial
+from typing import Callable, Literal, Sequence
 
 import jax
 import numpy as np
@@ -40,6 +41,7 @@ from repro.core.nnchain import (
     resolve_batch_algorithm,
     resolve_matrix_free,
 )
+from repro.obs import get_registry, phase
 
 Backend = Literal["auto", "serial", "distributed", "kernel"]
 Algorithm = Literal["auto", "lw", "nnchain", "twophase", "landmark"]
@@ -57,6 +59,9 @@ class ClusterResult:
     # the (n, n) matrix the tree was built on (enables exemplars)
     distances: np.ndarray | None = field(default=None, repr=False)
     metric: str | None = None          # metric used to embed points (None: raw matrix)
+    # trips of the one NN-chain loop that built the tree (its ``iters``);
+    # None for the LW engines, the batched and the approximate tiers
+    chain_trips: int | None = None
     linkage_matrix: np.ndarray = field(init=False, repr=False)
 
     def __post_init__(self) -> None:
@@ -201,6 +206,200 @@ def _interpret_input(data, method: str, metric: str | None,
     if not materialize:
         return None, arr, metric
     return build_distance_matrix(arr, metric), arr, metric
+
+
+def _choose_engine(
+    D,
+    points: np.ndarray | None,
+    used_metric: str | None,
+    n: int,
+    method: str,
+    *,
+    algorithm: str,
+    backend: str,
+    mesh,
+    variant: str,
+    stop_at_k: int,
+    distance_threshold: float | None,
+    compaction: bool | str,
+    matrix_free: bool | str,
+    n_landmarks: int | None,
+    seed: int,
+    refine: int,
+) -> tuple[str, str, Callable[[], tuple]]:
+    """Resolve :func:`cluster`'s knobs (its docstring is the reference).
+
+    Returns ``(algorithm, backend, launch)``: the resolved engine, and a
+    call that starts it and returns ``(result, D)`` — the engine's
+    ``LWResult``-like result, possibly still computing on the device, and
+    the distance matrix the tree is built on (``None`` where none exists).
+    Every contradiction among the knobs raises here, before any engine
+    runs.
+    """
+    if matrix_free not in (True, False, None, "auto"):
+        # validate up front — the LW branch never consults matrix_free, so
+        # without this a typo'd value would only error once n grows past
+        # the nnchain auto threshold
+        raise ValueError(
+            f"matrix_free must be a bool or 'auto', got {matrix_free!r}"
+        )
+    if matrix_free not in (None, "auto"):
+        matrix_free = bool(matrix_free)   # membership passed 0/1: same as bool
+    if matrix_free is True:
+        # matrix-free is an nnchain-family capability: an explicit request
+        # makes "auto" mean nnchain, and an explicit "lw" is a
+        # contradiction — never silently build the (n, n) matrix the
+        # caller opted out of.  An explicit nnchain/twophase/landmark
+        # already names a matrix-free-capable engine and stands.
+        if algorithm == "lw":
+            raise ValueError(
+                "matrix_free=True requires the NN-chain engine, but "
+                "algorithm='lw' pins the Lance-Williams loop (every LW "
+                "backend stores the dense matrix)"
+            )
+        if algorithm == "auto":
+            algorithm = "nnchain"
+
+    if n_landmarks is not None or refine != 0:
+        # the landmark knobs name the landmark tier, the same way
+        # matrix_free=True names the nnchain family: an explicit request
+        # makes "auto" mean landmark, any other explicit algorithm is a
+        # contradiction
+        if algorithm == "auto":
+            algorithm = "landmark"
+        elif algorithm != "landmark":
+            raise ValueError(
+                f"n_landmarks/refine belong to the landmark tier, but "
+                f"algorithm={algorithm!r} pins a different engine"
+            )
+
+    if backend == "auto":
+        # an explicit nnchain/twophase request owns the backend choice:
+        # their default composition is the serial chain, so "auto" must
+        # not hand them a multi-device mesh they did not ask for (the
+        # sharded chain is explicit backend="distributed" opt-in)
+        backend = (
+            "serial" if algorithm in ("nnchain", "twophase", "landmark")
+            else "distributed" if len(jax.devices()) > 1
+            else "serial"
+        )
+
+    points_capable = (
+        points is not None and points.ndim == 2
+        and method in POINTS_METHODS and used_metric == "sqeuclidean"
+    )
+
+    if algorithm == "landmark":
+        from repro.core.landmark import LANDMARK_METRICS, landmark_cluster
+
+        if points is None:
+            raise ValueError(
+                "algorithm='landmark' samples landmarks from coordinates "
+                "and assigns the rest through the streaming labeler: it "
+                "needs (n, d) points or (n, atoms, 3) conformations, not "
+                "a pre-built distance matrix (which already paid the "
+                "Ω(n²) evaluations this tier exists to avoid)"
+            )
+        if used_metric not in LANDMARK_METRICS:
+            raise ValueError(
+                f"algorithm='landmark' supports metrics {LANDMARK_METRICS} "
+                f"(the assignment labeler's), got {used_metric!r}"
+            )
+        if backend != "serial":
+            raise ValueError(
+                f"algorithm='landmark' is single-device (the whole point "
+                f"is that n·k work fits one host), got backend={backend!r}"
+            )
+        return algorithm, backend, lambda: (landmark_cluster(
+            points, method, metric=used_metric,
+            n_landmarks=n_landmarks, seed=seed, refine=refine,
+        ), None)
+
+    if algorithm == "twophase":
+        if not points_capable:
+            raise ValueError(
+                "algorithm='twophase' shards points and agglomerates "
+                "geometric summaries: it needs (n, d) points input and a "
+                f"method from {POINTS_METHODS} under the squared-"
+                f"Euclidean convention; got method={method!r}, "
+                f"metric={used_metric!r}, "
+                f"input shape {None if points is None else points.shape}"
+            )
+        if backend not in ("serial", "distributed"):
+            raise ValueError(
+                f"algorithm='twophase' supports backend='serial'/"
+                f"'distributed', got {backend!r}"
+            )
+        from repro.core.distributed import two_phase_from_points
+
+        return algorithm, backend, lambda: (
+            two_phase_from_points(points, method), None)
+
+    algorithm = resolve_algorithm(
+        algorithm, method=method, backend=backend, n=n,
+        variant=variant, compaction=compaction,
+    )
+
+    def on_matrix(engine: Callable) -> Callable[[], tuple]:
+        # the dense engines run on the input matrix, or on one built from
+        # the points when the engine starts
+        def launch() -> tuple:
+            D_ = D if points is None else build_distance_matrix(points,
+                                                                used_metric)
+            return engine(D_), D_
+        return launch
+
+    if algorithm == "nnchain":
+        if backend == "distributed":
+            # the sharded matrix-free chain (DESIGN.md §12) is the ONLY
+            # distributed chain composition — it needs the points
+            # capability, and matrix_free=False contradicts it
+            if matrix_free is False or not points_capable:
+                raise ValueError(
+                    "backend='distributed' with algorithm='nnchain' is "
+                    "the sharded matrix-free chain: it needs (n, d) "
+                    f"points input, a method from {POINTS_METHODS} under "
+                    "the squared-Euclidean convention, and matrix_free "
+                    f"left on (got method={method!r}, "
+                    f"metric={used_metric!r}, matrix_free={matrix_free!r}, "
+                    f"input shape "
+                    f"{None if points is None else points.shape}) — use "
+                    "algorithm='lw' for the dense row-sharded engine"
+                )
+            from repro.core.distributed import (
+                distributed_nn_chain_from_points,
+            )
+
+            return algorithm, backend, lambda: (
+                distributed_nn_chain_from_points(points, method, mesh=mesh),
+                None)
+        use_points = resolve_matrix_free(
+            matrix_free,
+            points_shape=None if points is None else points.shape,
+            method=method, metric=used_metric, n=n,
+        )
+        if use_points:
+            # the (n, n) matrix is never materialized — keep it that way
+            return algorithm, "serial", lambda: (
+                nn_chain_from_points(points, method), None)
+        return algorithm, "serial", on_matrix(lambda D_: nn_chain(D_, method))
+
+    if backend == "serial":
+        engine = lance_williams
+    elif backend == "distributed":
+        from repro.core.distributed import distributed_lance_williams
+
+        engine = partial(distributed_lance_williams, mesh=mesh)
+    elif backend == "kernel":
+        from repro.kernels.ops import lance_williams_kernelized
+
+        def engine(D_, **kw):
+            return lance_williams_kernelized(jax.numpy.asarray(D_), **kw)
+    else:
+        raise ValueError(f"unknown backend {backend!r}")
+    return algorithm, backend, on_matrix(lambda D_: engine(
+        D_, method=method, variant=variant, stop_at_k=stop_at_k,
+        distance_threshold=distance_threshold, compaction=compaction))
 
 
 def cluster(
@@ -351,227 +550,71 @@ def cluster(
     result (enables ``exemplars``/``centroids`` and the
     streaming-assignment export).  Pass ``False`` when accumulating many
     results; the pinned ``(n, n)`` matrix is O(n²) per result.
+
+    Each call times its phases as ``repro/cluster/*`` spans on the
+    profiler's clock and the process-global metrics registry, and counts
+    a chain engine's loop trips (DESIGN.md §13).
     """
-    if method not in METHODS:
-        raise ValueError(f"unknown linkage method {method!r}")
+    with phase("cluster"):
+        with phase("cluster/input"):
+            if method not in METHODS:
+                raise ValueError(f"unknown linkage method {method!r}")
+            D, points, used_metric = _interpret_input(
+                data, method, metric, is_distance, materialize=False
+            )
+            n = int((D if points is None else points).shape[0])
+            algorithm, backend, launch = _choose_engine(
+                D, points, used_metric, n, method,
+                algorithm=algorithm, backend=backend, mesh=mesh,
+                variant=variant, stop_at_k=stop_at_k,
+                distance_threshold=distance_threshold, compaction=compaction,
+                matrix_free=matrix_free, n_landmarks=n_landmarks, seed=seed,
+                refine=refine,
+            )
+        with phase("cluster/engine"):
+            res, D = launch()
+            jax.block_until_ready(res)
+        with phase("cluster/fetch"):
+            merges, n_merges, iters = jax.device_get(
+                (res.merges, res.n_merges, getattr(res, "iters", None))
+            )
+        chain_trips = None if iters is None else int(iters)
+        if chain_trips is not None:
+            get_registry().histogram(
+                "chain_trips", "NN-chain loop trips per chain call"
+            ).observe(chain_trips)
 
-    D, points, used_metric = _interpret_input(
-        data, method, metric, is_distance, materialize=False
-    )
-    n = int((D if points is None else points).shape[0])
-
-    if matrix_free not in (True, False, None, "auto"):
-        # validate up front — the LW branch never consults matrix_free, so
-        # without this a typo'd value would only error once n grows past
-        # the nnchain auto threshold
-        raise ValueError(
-            f"matrix_free must be a bool or 'auto', got {matrix_free!r}"
-        )
-    if matrix_free not in (None, "auto"):
-        matrix_free = bool(matrix_free)   # membership passed 0/1: same as bool
-    if matrix_free is True:
-        # matrix-free is an nnchain-family capability: an explicit request
-        # makes "auto" mean nnchain, and an explicit "lw" is a
-        # contradiction — never silently build the (n, n) matrix the
-        # caller opted out of.  An explicit nnchain/twophase/landmark
-        # already names a matrix-free-capable engine and stands.
         if algorithm == "lw":
-            raise ValueError(
-                "matrix_free=True requires the NN-chain engine, but "
-                "algorithm='lw' pins the Lance-Williams loop (every LW "
-                "backend stores the dense matrix)"
-            )
-        if algorithm == "auto":
-            algorithm = "nnchain"
-
-    if n_landmarks is not None or refine != 0:
-        # the landmark knobs name the landmark tier, the same way
-        # matrix_free=True names the nnchain family: an explicit request
-        # makes "auto" mean landmark, any other explicit algorithm is a
-        # contradiction
-        if algorithm == "auto":
-            algorithm = "landmark"
-        elif algorithm != "landmark":
-            raise ValueError(
-                f"n_landmarks/refine belong to the landmark tier, but "
-                f"algorithm={algorithm!r} pins a different engine"
-            )
-
-    if backend == "auto":
-        # an explicit nnchain/twophase request owns the backend choice:
-        # their default composition is the serial chain, so "auto" must
-        # not hand them a multi-device mesh they did not ask for (the
-        # sharded chain is explicit backend="distributed" opt-in)
-        backend = (
-            "serial" if algorithm in ("nnchain", "twophase", "landmark")
-            else "distributed" if len(jax.devices()) > 1
-            else "serial"
-        )
-
-    points_capable = (
-        points is not None and points.ndim == 2
-        and method in POINTS_METHODS and used_metric == "sqeuclidean"
-    )
-
-    if algorithm == "landmark":
-        from repro.core.landmark import LANDMARK_METRICS, landmark_cluster
-
-        if points is None:
-            raise ValueError(
-                "algorithm='landmark' samples landmarks from coordinates "
-                "and assigns the rest through the streaming labeler: it "
-                "needs (n, d) points or (n, atoms, 3) conformations, not "
-                "a pre-built distance matrix (which already paid the "
-                "Ω(n²) evaluations this tier exists to avoid)"
-            )
-        if used_metric not in LANDMARK_METRICS:
-            raise ValueError(
-                f"algorithm='landmark' supports metrics {LANDMARK_METRICS} "
-                f"(the assignment labeler's), got {used_metric!r}"
-            )
-        if backend != "serial":
-            raise ValueError(
-                f"algorithm='landmark' is single-device (the whole point "
-                f"is that n·k work fits one host), got backend={backend!r}"
-            )
-        res = landmark_cluster(
-            points, method, metric=used_metric,
-            n_landmarks=n_landmarks, seed=seed, refine=refine,
-        )
-        # heights are already monotone-repaired + canonical: only truncate
-        merges = dg.truncate_canonical(
-            np.asarray(res.merges), n, stop_at_k, distance_threshold
-        )
-        return ClusterResult(
-            merges=merges,
-            method=method,
-            backend=backend,
-            algorithm="landmark",
-            n_leaves=n,
-            points=points if keep_inputs else None,
-            distances=None,
-            metric=used_metric,
-        )
-
-    if algorithm == "twophase":
-        if not points_capable:
-            raise ValueError(
-                "algorithm='twophase' shards points and agglomerates "
-                "geometric summaries: it needs (n, d) points input and a "
-                f"method from {POINTS_METHODS} under the squared-"
-                f"Euclidean convention; got method={method!r}, "
-                f"metric={used_metric!r}, "
-                f"input shape {None if points is None else points.shape}"
-            )
-        if backend not in ("serial", "distributed"):
-            raise ValueError(
-                f"algorithm='twophase' supports backend='serial'/"
-                f"'distributed', got {backend!r}"
-            )
-        from repro.core.distributed import two_phase_from_points
-
-        res = two_phase_from_points(points, method)
-        # heights are already monotone-repaired + canonical: only truncate
-        merges = dg.truncate_canonical(
-            np.asarray(res.merges), n, stop_at_k, distance_threshold
-        )
-        return ClusterResult(
-            merges=merges,
-            method=method,
-            backend=backend,
-            algorithm="twophase",
-            n_leaves=n,
-            points=points if keep_inputs else None,
-            distances=None,
-            metric=used_metric,
-        )
-
-    algorithm = resolve_algorithm(
-        algorithm, method=method, backend=backend, n=n,
-        variant=variant, compaction=compaction,
-    )
-
-    if algorithm == "nnchain":
-        if backend == "distributed":
-            # the sharded matrix-free chain (DESIGN.md §12) is the ONLY
-            # distributed chain composition — it needs the points
-            # capability, and matrix_free=False contradicts it
-            if matrix_free is False or not points_capable:
-                raise ValueError(
-                    "backend='distributed' with algorithm='nnchain' is "
-                    "the sharded matrix-free chain: it needs (n, d) "
-                    f"points input, a method from {POINTS_METHODS} under "
-                    "the squared-Euclidean convention, and matrix_free "
-                    f"left on (got method={method!r}, "
-                    f"metric={used_metric!r}, matrix_free={matrix_free!r}, "
-                    f"input shape "
-                    f"{None if points is None else points.shape}) — use "
-                    "algorithm='lw' for the dense row-sharded engine"
+            merges = merges[: int(n_merges)]
+        else:
+            if algorithm == "nnchain":
+                if n > 1 and int(n_merges) != n - 1:
+                    raise RuntimeError(
+                        "NN-chain loop hit its iteration cap before "
+                        "finishing — the input likely contains NaNs (the "
+                        "chain invariant needs a total order on distances)"
+                    )
+                with phase("cluster/canonical_order"):
+                    merges = dg.canonical_order(merges, n=n)
+            # the landmark and two-phase tiers return heights already
+            # monotone-repaired and canonical: only truncate
+            with phase("cluster/truncate"):
+                merges = dg.truncate_canonical(
+                    merges, n, stop_at_k, distance_threshold
                 )
-            from repro.core.distributed import (
-                distributed_nn_chain_from_points,
-            )
 
-            res = distributed_nn_chain_from_points(points, method, mesh=mesh)
-            D = None
-        else:
-            use_points = resolve_matrix_free(
-                matrix_free,
-                points_shape=None if points is None else points.shape,
-                method=method, metric=used_metric, n=n,
+        with phase("cluster/result"):
+            return ClusterResult(
+                merges=merges,
+                method=method,
+                backend=backend,
+                algorithm=algorithm,
+                n_leaves=n,
+                points=points if keep_inputs else None,
+                distances=D if (keep_inputs and D is not None) else None,
+                metric=used_metric,
+                chain_trips=chain_trips,
             )
-            if use_points:
-                res = nn_chain_from_points(points, method)
-                D = None                # never materialized — keep it that way
-            else:
-                if points is not None:
-                    D = build_distance_matrix(points, used_metric)
-                res = nn_chain(D, method)
-            backend = "serial"
-        if n > 1 and int(res.n_merges) != n - 1:
-            raise RuntimeError(
-                "NN-chain loop hit its iteration cap before finishing — "
-                "the input likely contains NaNs (the chain invariant "
-                "needs a total order on distances)"
-            )
-        merges = dg.truncate_canonical(
-            dg.canonical_order(np.asarray(res.merges), n=n),
-            n, stop_at_k, distance_threshold,
-        )
-    else:
-        if points is not None:
-            D = build_distance_matrix(points, used_metric)
-        stops = dict(stop_at_k=stop_at_k,
-                     distance_threshold=distance_threshold,
-                     compaction=compaction)
-        if backend == "serial":
-            res = lance_williams(D, method=method, variant=variant, **stops)
-        elif backend == "distributed":
-            from repro.core.distributed import distributed_lance_williams
-
-            res = distributed_lance_williams(
-                D, method=method, mesh=mesh, variant=variant, **stops
-            )
-        elif backend == "kernel":
-            from repro.kernels.ops import lance_williams_kernelized
-
-            res = lance_williams_kernelized(
-                jax.numpy.asarray(D), method=method, variant=variant, **stops
-            )
-        else:
-            raise ValueError(f"unknown backend {backend!r}")
-        merges = np.asarray(res.merges)[: int(res.n_merges)]
-
-    return ClusterResult(
-        merges=merges,
-        method=method,
-        backend=backend,
-        algorithm=algorithm,
-        n_leaves=n,
-        points=points if keep_inputs else None,
-        distances=D if (keep_inputs and D is not None) else None,
-        metric=used_metric,
-    )
 
 
 @dataclass

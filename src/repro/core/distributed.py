@@ -100,6 +100,8 @@ class DistributedChainResult(NamedTuple):
     many shard-loss restarts it absorbed, how many segments straggled
     past the deadline.  The same counts feed the process-global metrics
     registry (``distributed_chain_*`` counters, DESIGN.md §13).
+    ``iters`` is the chain-loop trip count, as on
+    :class:`~repro.core.nnchain.ChainResult`.
     """
 
     merges: jax.Array
@@ -107,6 +109,7 @@ class DistributedChainResult(NamedTuple):
     restarts: int = 0
     stragglers: int = 0
     segments: int = 0
+    iters: jax.Array | None = None      # chain-loop trips, all segments
 
 
 def make_cluster_mesh(devices=None) -> Mesh:
@@ -677,6 +680,7 @@ def distributed_nn_chain_from_points(
         return DistributedChainResult(
             merges=jnp.zeros((0, 4), _F32),
             n_merges=jnp.zeros((), jnp.int32),
+            iters=jnp.zeros((), jnp.int32),
         )
     mesh = require_ring_mesh(mesh)
     p = int(mesh.devices.size)
@@ -804,6 +808,7 @@ def distributed_nn_chain_from_points(
     return DistributedChainResult(
         merges=state[6], n_merges=state[7],
         restarts=restarts, stragglers=stragglers, segments=seg_idx,
+        iters=state[8],
     )
 
 

@@ -8,8 +8,11 @@ One metrics substrate + one span substrate for the whole repo:
   ``service.cache.CacheStats`` sit on it; the distributed chain and
   fault runtime feed the process-global default (:func:`get_registry`).
 * :mod:`~repro.obs.trace` — :class:`Tracer` span API (context manager +
-  decorator + record-from-timestamps), per-request trace ids, Chrome
-  trace-event JSON export (renders in ``chrome://tracing`` / Perfetto).
+  record-from-timestamps), per-request trace ids, Chrome trace-event
+  JSON export (renders in ``chrome://tracing`` / Perfetto), and
+  :func:`phase`, the library's span (``cluster`` and its phases).
+  Context-manager spans also land on the JAX profiler's host plane as
+  ``repro/<name>`` annotations.
 * :mod:`~repro.obs.export` — Prometheus-style text exposition, JSON
   dump, and the periodic dumper the service load driver uses.
 
@@ -33,10 +36,18 @@ from repro.obs.registry import (
     get_registry,
     reset_registry,
 )
-from repro.obs.trace import NULL_TRACER, SpanEvent, Tracer, spans_by_name
+from repro.obs.trace import (
+    NULL_TRACER,
+    PHASE_SECONDS,
+    SpanEvent,
+    Tracer,
+    phase,
+    spans_by_name,
+)
 
 __all__ = [
     "NULL_TRACER",
+    "PHASE_SECONDS",
     "Counter",
     "Gauge",
     "Histogram",
@@ -46,6 +57,7 @@ __all__ = [
     "Tracer",
     "dump_json",
     "get_registry",
+    "phase",
     "prometheus_text",
     "registry_json",
     "reset_registry",

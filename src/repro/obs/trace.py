@@ -1,4 +1,4 @@
-"""Trace spans → Chrome trace-event JSON (DESIGN.md §13).
+"""Trace spans → Chrome trace-event JSON and the profiler (DESIGN.md §13).
 
 A :class:`Tracer` records **complete events** (``"ph": "X"`` in the
 Chrome trace-event format): name, category, start timestamp, duration,
@@ -7,6 +7,17 @@ thread id, and free-form ``args``.  Load the exported JSON in
 renders as the familiar flame view — spans on one thread nest by time
 containment, so the dispatcher's ``bucket`` span visibly contains its
 ``pack`` / ``cache`` / ``execute`` / ``resolve`` children.
+
+:func:`phase` is the library's span: ``cluster`` wraps each of its
+phases in one (``cluster``, ``cluster/input``, ``cluster/engine``, …),
+each observed into the histogram :data:`PHASE_SECONDS` of a registry.
+
+Every context-manager span — :func:`phase` and :meth:`Tracer.span` —
+also enters a ``jax.profiler.TraceAnnotation`` named ``repro/<name>``,
+so under ``jax.profiler.start_trace`` it lands on the profiler's host
+plane, on the same clock as the device operations.  With no profiler
+running the annotation is a sub-microsecond no-op.  Spans recorded
+after the fact (:meth:`Tracer.add_span`) carry only host timestamps.
 
 Per-request **trace ids** stitch the cross-thread story together: the
 caller-side ``submit`` span carries ``args.trace_id``; the dispatcher's
@@ -18,7 +29,8 @@ through the service.
 Design constraints (the §10 zero-recompile argument):
 
 * **host-side only** — spans wrap calls *into* compiled code, never code
-  inside a traced function.  Nothing here touches jax.
+  inside a traced function; the profiler annotation is a host event and
+  is never entered while JAX traces a function.
 * **bounded** — events land in a ``deque(maxlen=...)``; a long-lived
   service keeps the most recent window instead of leaking.
 * **cheap when off** — a disabled tracer's ``span()`` returns a shared
@@ -37,10 +49,17 @@ import json
 import threading
 import time
 from collections import deque
-from contextlib import contextmanager
 from dataclasses import dataclass, field
-from functools import wraps
-from typing import Iterable
+from typing import Callable, Iterable
+
+from jax.profiler import TraceAnnotation
+
+from repro.obs.registry import MetricsRegistry, get_registry
+
+#: Prefix of every span's profiler annotation.
+ANNOTATION_PREFIX = "repro/"
+#: Histogram of :func:`phase` seconds, labeled ``phase=<name>``.
+PHASE_SECONDS = "cluster_phase_seconds"
 
 
 @dataclass(frozen=True)
@@ -83,6 +102,33 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
+class _Span:
+    """Profiler annotation ``repro/<name>`` around a host-clock timing.
+
+    On exit, exception or not, ``done(t0, t1)`` gets the
+    ``perf_counter`` endpoints, taken inside the annotation.
+    """
+
+    __slots__ = ("_ann", "_done", "_value", "_t0")
+
+    def __init__(self, name: str, done: Callable[[float, float], None],
+                 value=None) -> None:
+        self._ann = TraceAnnotation(ANNOTATION_PREFIX + name)
+        self._done = done
+        self._value = value
+
+    def __enter__(self):
+        self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self._value
+
+    def __exit__(self, *exc):
+        t1 = time.perf_counter()
+        self._ann.__exit__(*exc)
+        self._done(self._t0, t1)
+        return False
+
+
 class Tracer:
     """Span recorder.  One per service run (or one global, your call).
 
@@ -123,34 +169,13 @@ class Tracer:
     # -- recording ------------------------------------------------------------
 
     def span(self, name: str, cat: str = "service", **args):
-        """Context manager timing one span.  No-op when disabled."""
+        """Context manager timing one span, also on the profiler's clock
+        (``repro/<name>``); yields ``args``, which the caller may add to
+        before exit.  No-op when disabled."""
         if not self.enabled:
             return _NULL_SPAN
-        return self._span(name, cat, args)
-
-    @contextmanager
-    def _span(self, name: str, cat: str, args: dict):
-        t0 = time.perf_counter()
-        try:
-            yield args      # callers may add result args before exit
-        finally:
-            t1 = time.perf_counter()
-            self._record(name, cat, t0, t1, args)
-
-    def trace(self, fn=None, *, name: str | None = None,
-              cat: str = "service"):
-        """Decorator form: ``@tracer.trace`` or ``@tracer.trace(name=...)``."""
-        def deco(f):
-            label = name or f.__qualname__
-
-            @wraps(f)
-            def wrapper(*a, **kw):
-                if not self.enabled:
-                    return f(*a, **kw)
-                with self._span(label, cat, {}):
-                    return f(*a, **kw)
-            return wrapper
-        return deco(fn) if fn is not None else deco
+        return _Span(name, lambda t0, t1: self._record(name, cat, t0, t1,
+                                                       args), args)
 
     def add_span(self, name: str, t0: float, t1: float, cat: str = "service",
                  **args) -> None:
@@ -222,3 +247,24 @@ NULL_TRACER = Tracer(enabled=False, max_events=1)
 def spans_by_name(events: Iterable[SpanEvent], name: str) -> list[SpanEvent]:
     """Test/analysis helper: all spans with a given name."""
     return [e for e in events if e.name == name]
+
+
+def phase(name: str, registry: MetricsRegistry | None = None,
+          tracer: Tracer | None = None) -> _Span:
+    """Context manager timing one phase of a library call.
+
+    Enters the profiler annotation ``repro/<name>``, observes the
+    phase's seconds into ``registry``'s :data:`PHASE_SECONDS` histogram
+    (``phase=<name>``; the process-global :func:`get_registry` by
+    default) on exit, exception or not, and, given a ``tracer``, records
+    the span there too.
+    """
+    hist = (get_registry() if registry is None else registry).histogram(
+        PHASE_SECONDS, "Seconds per phase of a library call")
+
+    def done(t0: float, t1: float) -> None:
+        hist.observe(t1 - t0, phase=name)
+        if tracer is not None:
+            tracer.add_span(name, t0, t1, cat="cluster")
+
+    return _Span(name, done)
