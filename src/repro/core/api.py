@@ -37,6 +37,7 @@ from repro.core.nnchain import (
     POINTS_METHODS,
     nn_chain,
     nn_chain_from_points,
+    points_stage_plan,
     resolve_algorithm,
     resolve_batch_algorithm,
     resolve_matrix_free,
@@ -59,9 +60,11 @@ class ClusterResult:
     # the (n, n) matrix the tree was built on (enables exemplars)
     distances: np.ndarray | None = field(default=None, repr=False)
     metric: str | None = None          # metric used to embed points (None: raw matrix)
-    # trips of the one NN-chain loop that built the tree (its ``iters``);
-    # None for the LW engines, the batched and the approximate tiers
+    # trips of the one NN-chain loop that built the tree (its ``iters``)
+    # and the stages that loop ran in (1: it never compacted); None for
+    # the LW engines, the batched and the approximate tiers
     chain_trips: int | None = None
+    chain_stages: int | None = None
     linkage_matrix: np.ndarray = field(init=False, repr=False)
 
     def __post_init__(self) -> None:
@@ -226,13 +229,15 @@ def _choose_engine(
     n_landmarks: int | None,
     seed: int,
     refine: int,
-) -> tuple[str, str, Callable[[], tuple]]:
+) -> tuple[str, str, Callable[[], tuple], int]:
     """Resolve :func:`cluster`'s knobs (its docstring is the reference).
 
-    Returns ``(algorithm, backend, launch)``: the resolved engine, and a
-    call that starts it and returns ``(result, D)`` — the engine's
-    ``LWResult``-like result, possibly still computing on the device, and
-    the distance matrix the tree is built on (``None`` where none exists).
+    Returns ``(algorithm, backend, launch, stages)``: the resolved
+    engine, a call that starts it and returns ``(result, D)`` — the
+    engine's ``LWResult``-like result, possibly still computing on the
+    device, and the distance matrix the tree is built on (``None`` where
+    none exists) — and the stages a chain engine's loop runs in, known
+    from its static plan (1 unless the serial matrix-free chain stages).
     Every contradiction among the knobs raises here, before any engine
     runs.
     """
@@ -313,7 +318,7 @@ def _choose_engine(
         return algorithm, backend, lambda: (landmark_cluster(
             points, method, metric=used_metric,
             n_landmarks=n_landmarks, seed=seed, refine=refine,
-        ), None)
+        ), None), 1
 
     if algorithm == "twophase":
         if not points_capable:
@@ -333,7 +338,7 @@ def _choose_engine(
         from repro.core.distributed import two_phase_from_points
 
         return algorithm, backend, lambda: (
-            two_phase_from_points(points, method), None)
+            two_phase_from_points(points, method), None), 1
 
     algorithm = resolve_algorithm(
         algorithm, method=method, backend=backend, n=n,
@@ -372,7 +377,7 @@ def _choose_engine(
 
             return algorithm, backend, lambda: (
                 distributed_nn_chain_from_points(points, method, mesh=mesh),
-                None)
+                None), 1
         use_points = resolve_matrix_free(
             matrix_free,
             points_shape=None if points is None else points.shape,
@@ -381,8 +386,10 @@ def _choose_engine(
         if use_points:
             # the (n, n) matrix is never materialized — keep it that way
             return algorithm, "serial", lambda: (
-                nn_chain_from_points(points, method), None)
-        return algorithm, "serial", on_matrix(lambda D_: nn_chain(D_, method))
+                nn_chain_from_points(points, method), None), len(
+                points_stage_plan(n, points.shape[1], n - 1))
+        return (algorithm, "serial",
+                on_matrix(lambda D_: nn_chain(D_, method)), 1)
 
     if backend == "serial":
         engine = lance_williams
@@ -399,7 +406,7 @@ def _choose_engine(
         raise ValueError(f"unknown backend {backend!r}")
     return algorithm, backend, on_matrix(lambda D_: engine(
         D_, method=method, variant=variant, stop_at_k=stop_at_k,
-        distance_threshold=distance_threshold, compaction=compaction))
+        distance_threshold=distance_threshold, compaction=compaction)), 1
 
 
 def cluster(
@@ -510,10 +517,14 @@ def cluster(
     **compaction** (LW only, any backend) — stage schedule (DESIGN.md
     §3): pack live rows into a half-size matrix each time the live count
     halves; merges unchanged, dense work ~0.57×.  ``"auto"`` (default)
-    stages whenever the plan has >1 stage.  The nnchain engine has no
-    dead-row traffic to compact — the knob is ignored there, and an
-    *explicitly* set value steers ``algorithm="auto"`` back to LW (the
-    knob names an LW execution schedule).
+    stages whenever the plan has >1 stage.  The knob is ignored by the
+    nnchain engine, and an *explicitly* set value steers
+    ``algorithm="auto"`` back to LW (the knob names an LW execution
+    schedule).  The serial matrix-free chain compacts its dead summaries
+    on its own schedule, with no knob (DESIGN.md §11): its row reads all
+    n summaries, so it stages whenever the summaries are large enough to
+    pay for the gather; the dense chain's O(n) row is not worth a gather
+    of its (n, n) matrix.
 
     **stop_at_k / distance_threshold** (any algorithm, any backend) —
     early termination, composable: stop at ``k`` remaining clusters
@@ -553,7 +564,7 @@ def cluster(
 
     Each call times its phases as ``repro/cluster/*`` spans on the
     profiler's clock and the process-global metrics registry, and counts
-    a chain engine's loop trips (DESIGN.md §13).
+    a chain engine's loop trips and stages (DESIGN.md §13).
     """
     with phase("cluster"):
         with phase("cluster/input"):
@@ -563,7 +574,7 @@ def cluster(
                 data, method, metric, is_distance, materialize=False
             )
             n = int((D if points is None else points).shape[0])
-            algorithm, backend, launch = _choose_engine(
+            algorithm, backend, launch, stages = _choose_engine(
                 D, points, used_metric, n, method,
                 algorithm=algorithm, backend=backend, mesh=mesh,
                 variant=variant, stop_at_k=stop_at_k,
@@ -578,11 +589,16 @@ def cluster(
             merges, n_merges, iters = jax.device_get(
                 (res.merges, res.n_merges, getattr(res, "iters", None))
             )
-        chain_trips = None if iters is None else int(iters)
-        if chain_trips is not None:
-            get_registry().histogram(
+        chain_trips = chain_stages = None
+        if iters is not None:
+            chain_trips, chain_stages = int(iters), stages
+            registry = get_registry()
+            registry.histogram(
                 "chain_trips", "NN-chain loop trips per chain call"
             ).observe(chain_trips)
+            registry.histogram(
+                "chain_stages", "stages of the NN-chain loop per chain call"
+            ).observe(chain_stages)
 
         if algorithm == "lw":
             merges = merges[: int(n_merges)]
@@ -614,6 +630,7 @@ def cluster(
                 distances=D if (keep_inputs and D is not None) else None,
                 metric=used_metric,
                 chain_trips=chain_trips,
+                chain_stages=chain_stages,
             )
 
 

@@ -53,6 +53,15 @@ Two compositions share the one chain loop:
   ``single``/``complete`` distances are min/max pair statistics with no
   O(d) sufficient summary — they stay on the dense path (DESIGN.md §11).
 
+  Unlike the dense row (O(n) per trip), the points row reads all n
+  summaries, dead slots included, so half the bytes of an average trip
+  are tombstones.  The serial points loop therefore runs in stages like
+  the LW loop's compaction schedule (:func:`repro.core.engine.plan_stages`):
+  each time the live count halves, one gather packs the live summaries,
+  in ascending slot order, into a half-size array, and the loop goes on
+  there until a stage's summaries fall under
+  :data:`CHAIN_STAGE_MIN_BYTES`.  Merges are unchanged (DESIGN.md §11).
+
 Early termination (``stop_at_k`` / ``distance_threshold``) is *post-hoc*
 here: the full agglomeration is O(n²) anyway, so
 :func:`repro.core.api.cluster` runs it, canonicalizes, and truncates the
@@ -83,7 +92,14 @@ from typing import Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
-from repro.core.engine import LWResult, _first_where, symmetrize
+from repro.core.engine import (
+    LWResult,
+    _first_where,
+    _live_perm,
+    plan_stages,
+    remap_merges,
+    symmetrize,
+)
 from repro.core.linkage import METHODS, update_row
 
 __all__ = [
@@ -143,6 +159,14 @@ NNCHAIN_BATCH_AUTO_MIN_N = 64
 #: capable inputs: below this the (n, n) build is a few MB and the dense
 #: row scan is faster than the summary arithmetic.
 MATRIX_FREE_AUTO_MIN_N = 4096
+
+#: Smallest summary array, in bytes, a stage of the serial matrix-free
+#: chain may shrink to: 4096 rows at d = 128.  Each further halving
+#: saves a smaller share of a tree's row traffic (one more stage at
+#: d = 128 would save ~0.3% of a tree on a v5e) and adds one more copy
+#: of the loop body to compile; this floor stops where the saving is
+#: that small.
+CHAIN_STAGE_MIN_BYTES = 2 * 2**20
 
 _F32 = jnp.float32
 _INF = jnp.float32(jnp.inf)
@@ -393,7 +417,11 @@ def _scalar_set(vec: jax.Array, idx: jax.Array, value) -> jax.Array:
 
 
 def _chain_loop(
-    ops: NNChainOps, state: NNState, n_steps: int | jax.Array
+    ops: NNChainOps,
+    state: NNState,
+    n_steps: int | jax.Array,
+    *,
+    max_iters: int | None = None,
 ) -> NNState:
     """Run the NN-chain loop until ``n_steps`` merges are recorded.
 
@@ -414,12 +442,16 @@ def _chain_loop(
     ``any(cond)`` + per-lane ``select`` — lanes whose target is met stop
     absorbing body results while slower lanes run on (the frozen-lane
     invariant, same mechanism as the LW ``distance_threshold`` loop).
+
+    ``max_iters`` overrides the trip cap of ``4n + 8`` (``n`` the carry's
+    slot count): a staged run keeps the cap of its full size across
+    stages, since ``iters`` is carried.
     """
     if isinstance(n_steps, int) and n_steps <= 0:
         return state
     n = state.alive.shape[0]
     ks = jnp.arange(n)
-    iter_cap = jnp.int32(4 * n + 8)
+    iter_cap = jnp.int32(4 * n + 8 if max_iters is None else max_iters)
 
     def cond(s: NNState):
         return (s.n_merges < n_steps) & (s.iters < iter_cap)
@@ -669,8 +701,97 @@ def _points_nnchain_ops(
     return NNChainOps(row=row, merge=merge)
 
 
+def _compact_chain(s: NNState, remap: jax.Array, half: int):
+    """One gather pass: pack the live summaries into ``half`` slots.
+
+    The permutation is the LW compaction's
+    (:func:`repro.core.engine._live_perm`): live slots keep their
+    ascending order, the order the loop's first-live pick, first-index
+    argmin and ``prev`` preference key on, so the merge sequence is
+    unchanged.  Every chain entry below ``chain_len`` is live (a merge
+    pops both slots it touches), so the stack is rewritten to compacted
+    ids by each slot's rank among the live ones.  Returns the new carry
+    and ``remap`` (compacted slot → original id).
+    """
+    live, p = _live_perm(s.alive, half)
+    rank = jnp.cumsum(s.alive.astype(jnp.int32)) - 1
+    W, u = s.rep
+    return s._replace(
+        rep=(W[p], jnp.where(live, u[p], 0.0)),
+        alive=live,
+        sizes=jnp.where(live, s.sizes[p], 0.0),
+        chain=jnp.clip(rank[s.chain[:half]], 0, half - 1),
+    ), remap[p]
+
+
+def points_stage_plan(
+    n: int,
+    d: int,
+    n_steps: int,
+    *,
+    align: int = 1,
+    stage_min_bytes: int = CHAIN_STAGE_MIN_BYTES,
+) -> tuple[tuple[int, int], ...]:
+    """The stage plan of the serial matrix-free chain on ``(n, d)``
+    summaries: :func:`repro.core.engine.plan_stages` with a floor of
+    ``stage_min_bytes`` of float32 summaries, aligned to ``align`` rows
+    (``block_n`` on the Pallas route, whose tiles need whole blocks).
+    Its length is the ``chain_stages`` a :func:`repro.core.api.cluster`
+    call reports."""
+    return plan_stages(n, n_steps,
+                       min_stage=stage_min_bytes // (4 * max(d, 1)),
+                       align=align)
+
+
+def _staged_points_chain(
+    W: jax.Array,
+    u: jax.Array,
+    alive: jax.Array,
+    sizes: jax.Array | None,
+    *,
+    method: str,
+    n_steps: int,
+    use_pallas: bool,
+    block_n: int,
+    interpret: bool,
+    stage_min_bytes: int,
+) -> ChainResult:
+    """The serial matrix-free chain, staged (module docstring).
+
+    Runs :func:`points_stage_plan` the way
+    :func:`repro.core.engine.staged_merge_loop` runs the LW plan: one
+    carried ``(n_steps, 4)`` merge buffer, each stage running the one
+    chain loop to its cumulative merge target and its rows rewritten to
+    original ids (:func:`repro.core.engine.remap_merges`).  The trip cap
+    stays the full size's, since ``iters`` carries on across stages.  A
+    one-stage plan is the loop with no gather and no remap.
+    """
+    n, d = W.shape
+    stages = points_stage_plan(
+        n, d, n_steps, align=block_n if use_pallas else 1,
+        stage_min_bytes=stage_min_bytes,
+    )
+    state = _init_state((W, u), alive, n_steps, sizes=sizes)
+    remap = jnp.arange(n, dtype=jnp.int32)
+    start = 0
+    for si, (size, steps) in enumerate(stages):
+        if si:
+            state, remap = _compact_chain(state, remap, size)
+        ops = _points_nnchain_ops(
+            method, size, use_pallas=use_pallas, block_n=block_n,
+            interpret=interpret,
+        )
+        state = _chain_loop(ops, state, start + steps, max_iters=4 * n + 8)
+        if si:
+            state = state._replace(merges=remap_merges(
+                state.merges, state.n_merges, remap, start, steps))
+        start += steps
+    return ChainResult(merges=state.merges, n_merges=state.n_merges,
+                       iters=state.iters)
+
+
 @partial(jax.jit, static_argnames=("method", "n_steps", "use_pallas",
-                                   "block_n", "interpret"))
+                                   "block_n", "interpret", "stage_min_bytes"))
 def _run_points(
     X: jax.Array,
     alive: jax.Array,
@@ -680,16 +801,14 @@ def _run_points(
     use_pallas: bool,
     block_n: int,
     interpret: bool,
+    stage_min_bytes: int = CHAIN_STAGE_MIN_BYTES,
 ) -> ChainResult:
     n = X.shape[0]
-    rep = (jnp.asarray(X, _F32), jnp.zeros((n,), _F32))
-    state = _init_state(rep, alive, n_steps)
-    ops = _points_nnchain_ops(
-        method, n, use_pallas=use_pallas, block_n=block_n, interpret=interpret
+    return _staged_points_chain(
+        jnp.asarray(X, _F32), jnp.zeros((n,), _F32), alive, None,
+        method=method, n_steps=n_steps, use_pallas=use_pallas,
+        block_n=block_n, interpret=interpret, stage_min_bytes=stage_min_bytes,
     )
-    out = _chain_loop(ops, state, n_steps)
-    return ChainResult(merges=out.merges, n_merges=out.n_merges,
-                       iters=out.iters)
 
 
 def nn_chain_from_points(
@@ -745,7 +864,7 @@ def nn_chain_from_points(
                        use_pallas=False, block_n=block_n, interpret=False)
 
 
-@partial(jax.jit, static_argnames=("method", "n_steps"))
+@partial(jax.jit, static_argnames=("method", "n_steps", "stage_min_bytes"))
 def _run_summaries(
     W: jax.Array,
     u: jax.Array,
@@ -753,17 +872,13 @@ def _run_summaries(
     *,
     method: str,
     n_steps: int,
+    stage_min_bytes: int = CHAIN_STAGE_MIN_BYTES,
 ) -> ChainResult:
-    n = W.shape[0]
-    state = _init_state(
-        (W, u), jnp.ones((n,), bool), n_steps, sizes=sizes
+    return _staged_points_chain(
+        W, u, jnp.ones((W.shape[0],), bool), sizes,
+        method=method, n_steps=n_steps, use_pallas=False, block_n=512,
+        interpret=False, stage_min_bytes=stage_min_bytes,
     )
-    ops = _points_nnchain_ops(
-        method, n, use_pallas=False, block_n=512, interpret=False
-    )
-    out = _chain_loop(ops, state, n_steps)
-    return ChainResult(merges=out.merges, n_merges=out.n_merges,
-                       iters=out.iters)
 
 
 def nn_chain_from_summaries(

@@ -131,6 +131,157 @@ def test_points_mode_pallas_route_matches_jnp(rng):
     np.testing.assert_allclose(a[:, 2], b[:, 2], rtol=1e-5, atol=1e-6)
 
 
+# ---------------------------------------------------------------------------
+# staged matrix-free chain (live summaries packed each time the count halves)
+# ---------------------------------------------------------------------------
+
+
+def _staged_pair(run, *args, d: int, **kw):
+    """One run staged at a floor of 16 rows, one run in a single stage."""
+    staged = run(*args, stage_min_bytes=4 * d * 16, **kw)
+    single = run(*args, stage_min_bytes=2**62, **kw)
+    return staged, single
+
+
+def _assert_identical(staged, single):
+    # same merges, same trips; heights bit-identical on the jnp path
+    assert np.array_equal(np.asarray(staged.merges), np.asarray(single.merges))
+    assert int(staged.n_merges) == int(single.n_merges)
+    assert int(staged.iters) == int(single.iters)
+
+
+def _staged_points(rng, case):
+    if case == "ties":     # duplicated quantised points: exact distance ties
+        base = np.round(rng.normal(size=(75, 4)) * 2) / 2
+        return np.repeat(base, 4, axis=0).astype(np.float32), 299
+    n = {"odd": 301, "non_power_of_two": 600, "partial": 600}[case]
+    X = rng.normal(size=(n, 8)).astype(np.float32)
+    return X, (400 if case == "partial" else n - 1)
+
+
+@pytest.mark.parametrize("method", POINTS_METHODS)
+@pytest.mark.parametrize("case", ["odd", "non_power_of_two", "ties",
+                                  "partial"])
+def test_staged_points_chain_equals_one_stage(rng, case, method):
+    from repro.core.nnchain import _run_points, points_stage_plan
+
+    X, n_steps = _staged_points(rng, case)
+    n, d = X.shape
+    staged, single = _staged_pair(
+        _run_points, X, np.ones((n,), bool), d=d, method=method,
+        n_steps=n_steps, use_pallas=False, block_n=512, interpret=False,
+    )
+    assert len(points_stage_plan(n, d, n_steps,
+                                 stage_min_bytes=4 * d * 16)) > 1
+    _assert_identical(staged, single)
+    assert int(staged.n_merges) == n_steps
+
+
+@pytest.mark.parametrize("method", POINTS_METHODS)
+def test_staged_summary_chain_equals_one_stage(rng, method):
+    """Phase 2 of the two-phase tier: slots are whole clusters with
+    pre-accumulated sizes and scatter terms."""
+    from repro.core.nnchain import _run_summaries, points_stage_plan
+
+    k = 333
+    W = rng.normal(size=(k, 6)).astype(np.float32)
+    u = (rng.random(k) * (method != "ward")).astype(np.float32)
+    sizes = rng.integers(1, 9, size=k).astype(np.float32)
+    staged, single = _staged_pair(_run_summaries, W, u, sizes, d=6,
+                                  method=method, n_steps=k - 1)
+    assert len(points_stage_plan(k, 6, k - 1, stage_min_bytes=4 * 6 * 16)) > 1
+    _assert_identical(staged, single)
+    assert np.asarray(staged.merges)[-1, 3] == sizes.sum()
+
+
+def test_staged_pallas_route_stops_where_the_half_breaks_block_alignment(rng):
+    """700 points pad to 768 slots, six 128-row blocks: the plan halves
+    to 384 and stops there, since 192 is no whole number of blocks."""
+    from repro.core.engine import plan_stages
+    from repro.core.nnchain import _run_points, points_stage_plan
+
+    n, n_pad, bn = 700, 768, 128
+    X = np.zeros((n_pad, 128), np.float32)
+    X[:n, :5] = rng.normal(size=(n, 5))
+    staged, single = _staged_pair(
+        _run_points, X, np.arange(n_pad) < n, d=128, method="ward",
+        n_steps=n - 1, use_pallas=True, block_n=bn, interpret=True,
+    )
+    assert points_stage_plan(n_pad, 128, n - 1, align=bn,
+                             stage_min_bytes=4 * 128 * 16) == (
+        (768, 384), (384, n - 1 - 384))
+    assert len(plan_stages(n_pad, n - 1, min_stage=16)) > 2   # unaligned
+    got, want = np.asarray(staged.merges), np.asarray(single.merges)
+    assert np.array_equal(got[:, [0, 1, 3]], want[:, [0, 1, 3]])
+    np.testing.assert_allclose(got[:, 2], want[:, 2], rtol=1e-6)
+    jnp_route = np.asarray(nn_chain_from_points(X[:n, :5], "ward").merges)
+    assert np.array_equal(got[:, [0, 1, 3]], jnp_route[:, [0, 1, 3]])
+
+
+def test_staged_chain_on_nan_input_ends_at_the_global_trip_cap(rng):
+    from repro.core.nnchain import _run_points, points_stage_plan
+
+    n = 300
+    X = rng.normal(size=(n, 4)).astype(np.float32)
+    X[17, 2] = np.nan
+    staged, single = _staged_pair(
+        _run_points, X, np.ones((n,), bool), d=4, method="ward",
+        n_steps=n - 1, use_pallas=False, block_n=512, interpret=False,
+    )
+    assert len(points_stage_plan(n, 4, n - 1, stage_min_bytes=4 * 4 * 16)) > 1
+    assert int(staged.iters) == int(single.iters) == 4 * n + 8
+    assert int(staged.n_merges) < n - 1
+    with pytest.raises(RuntimeError, match="iteration cap"):
+        cluster(X, "ward", matrix_free=True)
+
+
+@pytest.mark.parametrize("chain_len", [0, 1, 5])
+def test_compact_chain_packs_live_slots_in_ascending_order(rng, chain_len):
+    """One gather pass at a stage boundary: the live summaries, sizes
+    and original ids move to the front in ascending slot order, and the
+    chain stack is rewritten to the compacted ids."""
+    from repro.core.nnchain import NNState, _compact_chain
+
+    n, half, d = 64, 32, 3
+    live_ids = np.sort(rng.choice(n, size=half, replace=False))
+    alive = np.zeros(n, bool)
+    alive[live_ids] = True
+    W = rng.normal(size=(n, d)).astype(np.float32)
+    u = rng.random(n).astype(np.float32)
+    sizes = np.where(alive, rng.integers(1, 9, size=n), 0).astype(np.float32)
+    chain = np.zeros(n, np.int32)
+    chain[:chain_len] = rng.choice(live_ids, size=chain_len, replace=False)
+    remap = np.arange(100, 100 + n, dtype=np.int32)    # earlier stage's ids
+    state = NNState(rep=(W, u), alive=alive, sizes=sizes, chain=chain,
+                    chain_len=np.int32(chain_len),
+                    merges=np.zeros((n - 1, 4), np.float32),
+                    n_merges=np.int32(n - half), iters=np.int32(0))
+    out, new_remap = _compact_chain(state, remap, half)
+    assert np.asarray(out.alive).all()
+    assert np.array_equal(np.asarray(out.rep[0]), W[live_ids])
+    assert np.array_equal(np.asarray(out.rep[1]), u[live_ids])
+    assert np.array_equal(np.asarray(out.sizes), sizes[live_ids])
+    assert np.array_equal(np.asarray(new_remap), remap[live_ids])
+    got = np.asarray(out.chain)[:chain_len]
+    assert np.array_equal(live_ids[got], chain[:chain_len])
+
+
+@pytest.mark.parametrize("n,d,stages", [(48, 8, 1), (256, 4096, 2)])
+def test_cluster_observes_chain_stages(n, d, stages):
+    """One stage below the floor of CHAIN_STAGE_MIN_BYTES; 256 × 4096
+    float32 summaries are 4 MiB, so the plan halves once to 128 rows."""
+    from repro.core.nnchain import CHAIN_STAGE_MIN_BYTES
+    from repro.obs import reset_registry
+
+    assert (n * d * 4 >= 2 * CHAIN_STAGE_MIN_BYTES) == (stages > 1)
+    reg = reset_registry()
+    X = np.random.default_rng(3).normal(size=(n, d)).astype(np.float32)
+    res = cluster(X, "ward", matrix_free=True, keep_inputs=False)
+    assert res.chain_stages == stages
+    assert reg.get("chain_stages").window() == [stages]
+    assert reg.get("chain_trips").window() == [res.chain_trips]
+
+
 def test_row_kernel_matches_reference(rng):
     from repro.kernels.pairwise import row_sq_euclidean_pallas
 

@@ -132,3 +132,27 @@ def test_lw_update_compiles_for_v5e(one_chip):
 
     text = _compiled_text(update, vec, vec, scalar, scalar, scalar, vec, vec)
     assert "tpu_custom_call" in text
+
+
+def test_staged_points_chain_compiles_for_v5e(one_chip):
+    """The corpus cell's program: the matrix-free ward chain on 32768 ×
+    128 summaries, staged at 32768, 16384, 8192 and 4096 rows.  No
+    (n, n) buffer.  Temporaries hold the loop's copy of the summaries,
+    the half-size stages' copies (later stages reuse the first half's
+    room), the carried merge list, which the chip lays out 128 lanes
+    wide (16.8 MB for 32767 rows, as in the one-stage program), and
+    what the compiler keeps in fast memory, under half the summaries."""
+    from repro.core.nnchain import _run_points, points_stage_plan
+
+    n = 32_768
+    assert len(points_stage_plan(n, D, n - 1)) == 4
+    compiled = _run_points.lower(
+        _spec(one_chip, (n, D)), _spec(one_chip, (n,), jnp.bool_),
+        method="ward", n_steps=n - 1, use_pallas=False, block_n=512,
+        interpret=False,
+    ).compile()
+    assert f"[{n},{n}]" not in compiled.as_text()
+    summaries = n * D * 4
+    merge_list = (n - 1 + 7) // 8 * 8 * 128 * 4
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 2 * summaries + summaries // 2 + merge_list, temp
