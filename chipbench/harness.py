@@ -9,6 +9,13 @@ Everything a cell needs is found by name from ``BENCHMARK.json``:
   ``read(rec)`` takes the metric from the run's record and returns a
   number, or ``None`` when the record holds nothing to read.
 
+Which metrics a cell reports, so that a new cell needs no edit to an
+entry that is there: an end-to-end metric that lists ``workloads`` is
+reported in those cells; one that lists none, in every cell whose record
+its reader can read (``tree_s`` in every cell whose driver records
+trees).  A per-layer metric always lists its cells; an entry without the
+list is refused, so that none reaches a newly added cell unasked.
+
 A driver's ``run(run)`` sets the cell up, calls ``run.open_window()``,
 measures, calls ``run.close_window()``, and returns the record: a dict of
 what the readers read, plus ``answers`` (``(points, merges | None)`` pairs
@@ -64,14 +71,6 @@ class Cell:
     metrics_layer: list
 
 
-def _applies(metric: dict, cell: str, e2e_names: set[str]) -> bool:
-    """A per-layer metric is reported in the cells it lists, or, listing
-    none, in every cell that reports the end-to-end metric it moves."""
-    if "workloads" in metric:
-        return cell in metric["workloads"]
-    return metric["moves"] in e2e_names
-
-
 def load_cell(name: str, root: Path = ROOT) -> Cell:
     bench = load_json(root / "BENCHMARK.json")
     work = {w["name"]: w for w in bench["workloads"]}
@@ -86,10 +85,15 @@ def load_cell(name: str, root: Path = ROOT) -> Cell:
     driver = _load_module(root / "chipbench" / "drivers" /
                           (config["kind"] + ".py"),
                           f"chipbench_driver_{config['kind']}")
+    for m in bench["per_layer"]:
+        if "workloads" not in m:
+            raise ValueError(f"per-layer metric {m['name']!r} in "
+                             "BENCHMARK.json lists no workloads: a "
+                             "per-layer metric names the cells it is "
+                             "reported in")
     e2e = [m for m in bench["end_to_end"]
            if "workloads" not in m or name in m["workloads"]]
-    names = {m["name"] for m in e2e}
-    layer = [m for m in bench["per_layer"] if _applies(m, name, names)]
+    layer = [m for m in bench["per_layer"] if name in m["workloads"]]
     return Cell(name, w, config, traffic, driver, e2e, layer)
 
 
@@ -97,6 +101,17 @@ def reader(name: str, root: Path = ROOT):
     mod = _load_module(root / "chipbench" / "metrics" / (name + ".py"),
                        "chipbench_metric_" + name.replace(".", "_"))
     return mod.read
+
+
+def read_metrics(metrics: list, rec: dict, root: Path = ROOT) -> dict:
+    """The result line's metrics: each of ``metrics`` whose reader finds
+    something in the record, by name."""
+    out = {}
+    for m in metrics:
+        value = reader(m["name"], root)(rec)
+        if value is not None:
+            out[m["name"]] = {"value": value, "unit": m["unit"]}
+    return out
 
 
 def device_info(chips: int, require_tpu: bool) -> dict:
@@ -257,11 +272,8 @@ def run_cell(name: str, seed: int, seconds: float, trace: bool, *,
     from chipbench.reference import judge
 
     correct, checks = judge(numbers, limits)
-    metrics = {}
-    for m in (cell.metrics_layer if trace else cell.metrics_e2e):
-        value = reader(m["name"], root)(rec)
-        if value is not None:
-            metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    metrics = read_metrics(cell.metrics_layer if trace else cell.metrics_e2e,
+                           rec, root)
     device["memory_peak_bytes"] = run.memory_peak_bytes
     if device["kind"] in PEAKS:
         rec.setdefault("notes", {})["memory_peak_share"] = (
