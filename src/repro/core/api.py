@@ -60,11 +60,13 @@ class ClusterResult:
     # the (n, n) matrix the tree was built on (enables exemplars)
     distances: np.ndarray | None = field(default=None, repr=False)
     metric: str | None = None          # metric used to embed points (None: raw matrix)
-    # trips of the one NN-chain loop that built the tree (its ``iters``)
-    # and the stages that loop ran in (1: it never compacted); None for
-    # the LW engines, the batched and the approximate tiers
+    # trips of the one NN-chain loop that built the tree (its ``iters``),
+    # the stages that loop ran in (1: it never compacted) and the shards
+    # it ran over (1 but on the sharded chain); None for the LW engines,
+    # the batched and the approximate tiers
     chain_trips: int | None = None
     chain_stages: int | None = None
+    chain_shards: int | None = None
     linkage_matrix: np.ndarray = field(init=False, repr=False)
 
     def __post_init__(self) -> None:
@@ -564,7 +566,7 @@ def cluster(
 
     Each call times its phases as ``repro/cluster/*`` spans on the
     profiler's clock and the process-global metrics registry, and counts
-    a chain engine's loop trips and stages (DESIGN.md §13).
+    a chain engine's loop trips, stages and shards (DESIGN.md §13).
     """
     with phase("cluster"):
         with phase("cluster/input"):
@@ -589,9 +591,10 @@ def cluster(
             merges, n_merges, iters = jax.device_get(
                 (res.merges, res.n_merges, getattr(res, "iters", None))
             )
-        chain_trips = chain_stages = None
+        chain_trips = chain_stages = chain_shards = None
         if iters is not None:
             chain_trips, chain_stages = int(iters), stages
+            chain_shards = getattr(res, "shards", 1)
             registry = get_registry()
             registry.histogram(
                 "chain_trips", "NN-chain loop trips per chain call"
@@ -599,6 +602,9 @@ def cluster(
             registry.histogram(
                 "chain_stages", "stages of the NN-chain loop per chain call"
             ).observe(chain_stages)
+            registry.histogram(
+                "chain_shards", "mesh size of the NN-chain loop per chain call"
+            ).observe(chain_shards)
 
         if algorithm == "lw":
             merges = merges[: int(n_merges)]
@@ -631,6 +637,7 @@ def cluster(
                 metric=used_metric,
                 chain_trips=chain_trips,
                 chain_stages=chain_stages,
+                chain_shards=chain_shards,
             )
 
 

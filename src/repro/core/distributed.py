@@ -88,7 +88,7 @@ from repro.core.nnchain import (
     summary_merge,
 )
 from repro.distributed.fault import SimulatedFailure, StepDeadline
-from repro.obs import NULL_TRACER, Tracer, get_registry
+from repro.obs import NULL_TRACER, Tracer, get_registry, phase
 
 
 class DistributedChainResult(NamedTuple):
@@ -101,7 +101,8 @@ class DistributedChainResult(NamedTuple):
     past the deadline.  The same counts feed the process-global metrics
     registry (``distributed_chain_*`` counters, DESIGN.md §13).
     ``iters`` is the chain-loop trip count, as on
-    :class:`~repro.core.nnchain.ChainResult`.
+    :class:`~repro.core.nnchain.ChainResult`; ``shards`` the size of the
+    mesh the loop finished on.
     """
 
     merges: jax.Array
@@ -110,6 +111,7 @@ class DistributedChainResult(NamedTuple):
     stragglers: int = 0
     segments: int = 0
     iters: jax.Array | None = None      # chain-loop trips, all segments
+    shards: int = 1
 
 
 def make_cluster_mesh(devices=None) -> Mesh:
@@ -663,8 +665,11 @@ def distributed_nn_chain_from_points(
     process-global registry (``distributed_chain_segments_total``,
     ``..._restarts_total``, ``..._straggler_segments_total``) and, with
     a ``tracer``, every segment dispatch becomes a ``chain_segment``
-    span in the exported trace.  All of it host-side — the compiled
-    program is untouched.
+    span in the exported trace.  Two phases (:func:`repro.obs.phase`)
+    split the call: ``sharded_chain/place`` (padding and placing the
+    state on the mesh, to its sync) and ``sharded_chain/loop`` (the
+    segments, to the last one's merge count).  All of it host-side — the
+    compiled program is untouched.
     """
     if method not in POINTS_METHODS:
         raise ValueError(
@@ -697,23 +702,24 @@ def distributed_nn_chain_from_points(
         bn = block_n
         n_pad = pad_to_mesh(n, p)
         d_pad = d
-    if (n_pad, d_pad) != (n, d):
-        X = jnp.pad(X, ((0, n_pad - n), (0, d_pad - d)))
-
     from repro.distributed.sharding import replicate, shard_rows
 
-    alive = jnp.arange(n_pad) < n
-    state = (
-        shard_rows(X, mesh),                                   # W  (n·d/p)
-        replicate(jnp.zeros((n_pad,), _F32), mesh),            # u
-        replicate(alive, mesh),                                # alive
-        replicate(alive.astype(_F32), mesh),                   # sizes
-        replicate(jnp.zeros((n_pad,), jnp.int32), mesh),       # chain
-        replicate(jnp.zeros((), jnp.int32), mesh),             # chain_len
-        replicate(jnp.zeros((n - 1, 4), _F32), mesh),          # merges
-        replicate(jnp.zeros((), jnp.int32), mesh),             # n_merges
-        replicate(jnp.zeros((), jnp.int32), mesh),             # iters
-    )
+    with phase("sharded_chain/place"):
+        if (n_pad, d_pad) != (n, d):
+            X = jnp.pad(X, ((0, n_pad - n), (0, d_pad - d)))
+        alive = jnp.arange(n_pad) < n
+        state = (
+            shard_rows(X, mesh),                               # W  (n·d/p)
+            replicate(jnp.zeros((n_pad,), _F32), mesh),        # u
+            replicate(alive, mesh),                            # alive
+            replicate(alive.astype(_F32), mesh),               # sizes
+            replicate(jnp.zeros((n_pad,), jnp.int32), mesh),   # chain
+            replicate(jnp.zeros((), jnp.int32), mesh),         # chain_len
+            replicate(jnp.zeros((n - 1, 4), _F32), mesh),      # merges
+            replicate(jnp.zeros((), jnp.int32), mesh),         # n_merges
+            replicate(jnp.zeros((), jnp.int32), mesh),         # iters
+        )
+        jax.block_until_ready(state)
 
     n_steps = n - 1
     seg = n_steps if segment_steps is None else max(1, int(segment_steps))
@@ -730,75 +736,78 @@ def distributed_nn_chain_from_points(
         "distributed_chain_shrinks_total",
         "Elastic reshard-to-fallback-mesh events")
     done, seg_idx, restarts, stragglers = 0, 0, 0, 0
-    while done < n_steps:
-        target = min(done + seg, n_steps)
-        t0 = time.perf_counter()
-        try:
-            if failure_plan is not None:
-                failure_plan.check(seg_idx)
-            state = _run_sharded_chain(
-                *state, target, method=method, mesh=mesh,
-                use_pallas=use_pallas, block_n=bn, interpret=interpret,
-            )
-            made = int(state[7])        # syncs the segment (timing + fault)
-        except SimulatedFailure as e:
-            restarts += 1
-            restart_counter.inc()
-            tracer.add_span(
-                "chain_segment", t0, time.perf_counter(), cat="distributed",
-                segment=seg_idx, error="shard-lost", restarts=restarts,
-            )
-            if restarts > max_restarts:
-                if fallback_mesh is None:
-                    raise RuntimeError(
-                        f"distributed NN-chain lost a shard at segment "
-                        f"{seg_idx} and exceeded max_restarts={max_restarts} "
-                        f"(committed {done}/{n_steps} merges, p={p}, n={n}); "
-                        "the last consistent sharded state is still on the "
-                        "mesh — re-dispatch with a fresh failure budget to "
-                        "continue, or pass fallback_mesh= to shrink "
-                        "elastically"
-                    ) from e
-                # elastic shrink: validate (loudly, naming offending
-                # leaves/axes) then reshard the live state; same segment
-                # retried on the smaller mesh with a fresh budget
-                mesh, state = _shrink_chain_state(
-                    state, fallback_mesh, n_pad=n_pad, d_pad=d_pad, n=n,
-                    exhausted_p=p, cause=e, log=log,
+    with phase("sharded_chain/loop"):
+        while done < n_steps:
+            target = min(done + seg, n_steps)
+            t0 = time.perf_counter()
+            try:
+                if failure_plan is not None:
+                    failure_plan.check(seg_idx)
+                state = _run_sharded_chain(
+                    *state, target, method=method, mesh=mesh,
+                    use_pallas=use_pallas, block_n=bn, interpret=interpret,
                 )
-                p = int(mesh.devices.size)
-                fallback_mesh = None    # one shrink per run
-                restarts = 0
-                shrink_counter.inc()
+                made = int(state[7])    # syncs the segment (timing + fault)
+            except SimulatedFailure as e:
+                restarts += 1
+                restart_counter.inc()
+                tracer.add_span(
+                    "chain_segment", t0, time.perf_counter(),
+                    cat="distributed", segment=seg_idx, error="shard-lost",
+                    restarts=restarts,
+                )
+                if restarts > max_restarts:
+                    if fallback_mesh is None:
+                        raise RuntimeError(
+                            f"distributed NN-chain lost a shard at segment "
+                            f"{seg_idx} and exceeded "
+                            f"max_restarts={max_restarts} (committed "
+                            f"{done}/{n_steps} merges, p={p}, n={n}); the "
+                            "last consistent sharded state is still on the "
+                            "mesh — re-dispatch with a fresh failure budget "
+                            "to continue, or pass fallback_mesh= to shrink "
+                            "elastically"
+                        ) from e
+                    # elastic shrink: validate (loudly, naming offending
+                    # leaves/axes) then reshard the live state; same segment
+                    # retried on the smaller mesh with a fresh budget
+                    mesh, state = _shrink_chain_state(
+                        state, fallback_mesh, n_pad=n_pad, d_pad=d_pad, n=n,
+                        exhausted_p=p, cause=e, log=log,
+                    )
+                    p = int(mesh.devices.size)
+                    fallback_mesh = None    # one shrink per run
+                    restarts = 0
+                    shrink_counter.inc()
+                    continue
+                _fault_event(
+                    log,
+                    f"[fault] {e} — retrying segment {seg_idx} "
+                    f"({restarts}/{max_restarts}); the sharded state is the "
+                    "checkpoint, no merges lost",
+                )
                 continue
-            _fault_event(
-                log,
-                f"[fault] {e} — retrying segment {seg_idx} "
-                f"({restarts}/{max_restarts}); the sharded state is the "
-                "checkpoint, no merges lost",
+            t1 = time.perf_counter()
+            dt = t1 - t0
+            seg_counter.inc()
+            tracer.add_span(
+                "chain_segment", t0, t1, cat="distributed",
+                segment=seg_idx, merges_done=int(state[7]), target=target,
             )
-            continue
-        t1 = time.perf_counter()
-        dt = t1 - t0
-        seg_counter.inc()
-        tracer.add_span(
-            "chain_segment", t0, t1, cat="distributed",
-            segment=seg_idx, merges_done=int(state[7]), target=target,
-        )
-        if deadline is not None and deadline.observe(dt):
-            stragglers += 1
-            straggler_counter.inc()
-            _fault_event(
-                log,
-                f"[fault] segment {seg_idx} straggled ({dt:.3f}s > "
-                f"{deadline.factor}x median) — delayed shard flagged; "
-                "run continues",
-            )
-        seg_idx += 1
-        if made < target:               # iteration cap inside the segment
+            if deadline is not None and deadline.observe(dt):
+                stragglers += 1
+                straggler_counter.inc()
+                _fault_event(
+                    log,
+                    f"[fault] segment {seg_idx} straggled ({dt:.3f}s > "
+                    f"{deadline.factor}x median) — delayed shard flagged; "
+                    "run continues",
+                )
+            seg_idx += 1
+            if made < target:               # iteration cap inside the segment
+                done = made
+                break
             done = made
-            break
-        done = made
     if done != n_steps:
         raise RuntimeError(
             "sharded NN-chain hit its iteration cap before finishing — "
@@ -808,7 +817,7 @@ def distributed_nn_chain_from_points(
     return DistributedChainResult(
         merges=state[6], n_merges=state[7],
         restarts=restarts, stragglers=stragglers, segments=seg_idx,
-        iters=state[8],
+        iters=state[8], shards=p,
     )
 
 

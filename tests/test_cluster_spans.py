@@ -1,7 +1,8 @@
-"""Spans and the trip histogram of ``cluster`` (DESIGN.md §13): the
-``repro/cluster`` phases on the profiler's host plane, one histogram
-observation per phase per call, and the chain's trip count on the result
-and the registry."""
+"""Spans and the chain histograms of ``cluster`` (DESIGN.md §13): the
+``repro/cluster`` phases on the profiler's host plane, the sharded
+chain's own phases inside ``cluster/engine``, one histogram observation
+per phase per call, and the chain's trips and shards on the result and
+the registry."""
 
 import glob
 import os
@@ -19,6 +20,8 @@ CHAIN_PHASES = ("cluster", "cluster/input", "cluster/engine", "cluster/fetch",
                 "cluster/result")
 LW_PHASES = ("cluster", "cluster/input", "cluster/engine", "cluster/fetch",
              "cluster/result")
+SHARDED_PHASES = ("sharded_chain/place", "sharded_chain/loop")
+SHARDED = {"algorithm": "nnchain", "backend": "distributed"}
 
 
 def _points(n=48, d=8, seed=0):
@@ -117,11 +120,62 @@ def test_sharded_chain_reports_the_serial_chains_trips():
     assert reg.get("chain_trips").window() == [res.chain_trips]
 
 
+def test_sharded_chain_phases_nest_inside_the_engine_span(tmp_path):
+    X = _points()
+    cluster(X, "ward", **SHARDED)               # compile outside the trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        cluster(X, "ward", **SHARDED)
+    finally:
+        jax.profiler.stop_trace()
+    spans = _host_spans(str(tmp_path))
+    assert sorted(name for name, _, _ in spans) == sorted(
+        "repro/" + p for p in CHAIN_PHASES + SHARDED_PHASES)
+    (engine,) = [s for s in spans if s[0] == "repro/cluster/engine"]
+    place, loop = sorted((s for s in spans
+                          if s[0].startswith("repro/sharded_chain/")),
+                         key=lambda s: s[1])
+    assert [place[0], loop[0]] == ["repro/" + p for p in SHARDED_PHASES]
+    assert engine[1] <= place[1] <= place[2] <= loop[1] <= loop[2] <= engine[2]
+
+
+@pytest.mark.parametrize("calls", [1, 3])
+def test_sharded_chain_adds_one_place_and_loop_per_call(calls):
+    reg = reset_registry()
+    X = _points(n=40, seed=2)
+    for _ in range(calls):
+        cluster(X, "ward", **SHARDED)
+    phases = CHAIN_PHASES + SHARDED_PHASES
+    assert _counts(reg, phases) == dict.fromkeys(phases, calls)
+    hist = reg.get(PHASE_SECONDS)
+    engine = hist.window(phase="cluster/engine")
+    for p in SHARDED_PHASES:
+        assert all(0 < t < e for t, e in zip(hist.window(phase=p), engine))
+
+
+CHAIN_ROUTES = {"matrix_free": {"matrix_free": True},
+                "dense": {"algorithm": "nnchain", "matrix_free": False},
+                "sharded": SHARDED}
+
+
+@pytest.mark.parametrize("route", sorted(CHAIN_ROUTES))
+def test_chain_shards_on_result_and_registry(route):
+    """The mesh size on the sharded chain, 1 on the one-device chains."""
+    reg = reset_registry()
+    res = cluster(_points(n=40, seed=3), "ward", **CHAIN_ROUTES[route])
+    shards = jax.device_count() if route == "sharded" else 1
+    assert res.chain_shards == shards
+    assert reg.get("chain_shards").window() == [shards]
+    # only the sharded chain runs the sharded phases
+    assert reg.get(PHASE_SECONDS).count(phase="sharded_chain/loop") == (
+        route == "sharded")
+
+
 @pytest.mark.parametrize("backend", ["serial", "kernel"])
 def test_lw_call_records_its_spans_and_no_chain_trips(backend):
     reg = reset_registry()
     res = cluster(_points(n=24), "complete", algorithm="lw", backend=backend)
-    assert res.chain_trips is None
+    assert res.chain_trips is None and res.chain_shards is None
     assert _counts(reg, LW_PHASES) == dict.fromkeys(LW_PHASES, 1)
     assert _counts(reg, ("cluster/canonical_order", "cluster/truncate")) == {
         "cluster/canonical_order": 0, "cluster/truncate": 0}
@@ -133,6 +187,7 @@ def test_approximate_tiers_truncate_and_report_no_chain_trips(algorithm):
     reg = reset_registry()
     res = cluster(_points(n=64), "ward", algorithm=algorithm, stop_at_k=4)
     assert res.chain_trips is None and res.n_merges == 64 - 4
+    assert res.chain_shards is None and reg.get("chain_shards") is None
     phases = LW_PHASES + ("cluster/truncate",)
     assert _counts(reg, phases) == dict.fromkeys(phases, 1)
     assert reg.get("chain_trips") is None

@@ -6,10 +6,14 @@ and Pallas row-tile route run in subprocesses with fake devices, same as
 the distributed-LW suite.
 """
 
+from pathlib import Path
+
 import numpy as np
 import pytest
 
 from tests.conftest import run_with_devices
+
+REPO = Path(__file__).resolve().parents[1]
 
 
 def _mixture(n_per=24, k=6, d=5, seed=0, spread=20.0, noise=0.1):
@@ -172,6 +176,49 @@ for n, method in ((41, "ward"), (64, "average"), (37, "weighted")):
     assert np.array_equal(ser, dist), (n, method)
 print("OK")
 """)
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("n, d", [(8192, 128), (4099, 256)])
+def test_cluster_sharded_route_equals_staged_serial_route(n, d):
+    """``cluster``'s sharded chain on four devices against its one-device
+    chain at an n where that chain stages (2 stages at the default
+    floor; 4099 leaves a padded row on the mesh): the same merges and
+    trips, the benchmark's float64 reference within the limits of its
+    ``back-to-back`` mix, four shards and one stage on the result and
+    the registry, and one ``sharded_chain/place`` and one
+    ``sharded_chain/loop`` phase per call inside its ``cluster/engine``."""
+    run_with_devices(f"""
+import json, sys
+sys.path.insert(0, {str(REPO)!r})
+import numpy as np, jax
+from chipbench.generate import gaussian_mixture, rng_for
+from chipbench.reference import Tally, judge, reference_tree
+from repro.core import cluster
+from repro.obs import PHASE_SECONDS, get_registry
+assert jax.device_count() == 4
+X = gaussian_mixture(rng_for(2**31 + 11, 0), {n}, {d}, 16)
+one = cluster(X, "ward", backend="serial", keep_inputs=False)
+four = cluster(X, "ward", algorithm="nnchain", backend="distributed",
+               keep_inputs=False)
+assert one.chain_stages == 2 and one.chain_shards == 1, one
+assert four.chain_stages == 1 and four.chain_shards == 4, four
+assert np.array_equal(one.merges, four.merges)
+assert four.chain_trips == one.chain_trips
+tally = Tally()
+tally.add(four.merges, reference_tree(X, "ward"))
+limits = json.load(open({str(REPO / "chipbench/traffic/back-to-back.json")!r}))
+ok, checks = judge(tally.numbers(), limits["limits"])
+assert ok, checks
+reg = get_registry()
+assert reg.get("chain_shards").window() == [1, 4]
+hist = reg.get(PHASE_SECONDS)
+(engine,) = hist.window(phase="cluster/engine")[1:]
+for p in ("sharded_chain/place", "sharded_chain/loop"):
+    (t,) = hist.window(phase=p)
+    assert 0 < t < engine, (p, t, engine)
+print("OK")
+""", n_devices=4)
 
 
 @pytest.mark.slow

@@ -175,6 +175,7 @@ res = distributed_nn_chain_from_points(
 # the shrink kept the committed state: merges are the serial chain's
 assert np.array_equal(ser, np.asarray(res.merges))
 assert any("resharding" in e and "p=2" in e for e in events), events
+assert res.shards == 2          # the mesh the loop finished on
 assert get_registry().counter(
     "distributed_chain_shrinks_total", "").total() == before + 1
 
