@@ -6,12 +6,24 @@ which is exactly the paper's "output the current tree level" step.  This
 module is the host-side post-processing: conversion to a scipy-style
 linkage matrix, flat cluster extraction at any level ``k`` (the paper's
 "look k levels down the tree"), and tree invariant checks used by the
-property tests.  Pure numpy; nothing here is performance-critical.
+property tests.  Pure numpy.
+
+``cluster`` runs :func:`canonical_order` (with :func:`validate_merges`)
+and :func:`to_linkage_matrix` on every tree after the device finishes,
+so those three are on the host's critical path: each is vectorized over
+the merges through one *slot-writer index* (:func:`_slot_writers`),
+O(n log n) with no Python loop per merge.
 """
 
 from __future__ import annotations
 
 import numpy as np
+
+from repro.obs import get_registry
+
+# Jacobi rounds of canonical_order's height clamp before it falls back to
+# the sequential scan (a clamp chained down a deep tree)
+_CLAMP_ROUNDS = 8
 
 
 def _leaf_count(merges: np.ndarray, n: int | None) -> int:
@@ -25,6 +37,48 @@ def _leaf_count(merges: np.ndarray, n: int | None) -> int:
     return n
 
 
+def _slot_indices(merges: np.ndarray, n: int) -> tuple[np.ndarray, np.ndarray]:
+    """Slots ``i`` and ``j`` of every merge as int64 indices into ``n``
+    slots, with numpy's indexing rules: a negative slot counts from the
+    end, and the first slot (in merge order, ``i`` before ``j``) that is
+    not finite or out of range raises what indexing it would raise."""
+    r = np.rint(merges[:, :2].astype(np.float64))
+    bad = ~np.isfinite(r) | (r < -n) | (r >= n)
+    if bad.any():
+        t, k = np.argwhere(bad)[0]
+        s = int(round(merges[t, k]))     # ValueError/OverflowError if not finite
+        raise IndexError(f"index {s} is out of bounds for axis 0 with size {n}")
+    ij = r.astype(np.int64)
+    ij[ij < 0] += n
+    return ij[:, 0], ij[:, 1]
+
+
+def _slot_writers(i: np.ndarray, j: np.ndarray) -> tuple[np.ndarray, np.ndarray]:
+    """For each merge ``t``, the last earlier merge that wrote its slot
+    ``i`` (resp. ``j``) — a merge writes the slot ``i`` that keeps the
+    union — or −1 where the slot still holds its leaf.
+
+    One sort of the ``2m`` packed keys ``(slot, 2t + kind)`` — kind 0
+    for the query of ``j``, 1 for ``i``, which is also merge ``t``'s
+    write — then a running max over the write positions: the last write
+    strictly before a key, if it names the same slot, is the answer.
+    O(m log m), no Python loop.  Slots must be in ``[0, n)``.
+    """
+    m = i.shape[0]
+    bits = int(2 * m).bit_length()      # room for 2t + kind
+    t2 = 2 * np.arange(m, dtype=np.int64)
+    keys = np.sort(np.concatenate([(j << bits) | t2, (i << bits) | (t2 + 1)]))
+    slot, low = keys >> bits, keys & ((1 << bits) - 1)
+    writes = np.where((low & 1) == 1, np.arange(2 * m), -1)
+    # position of the last write strictly before each key (-1: none)
+    prev = np.maximum.accumulate(np.concatenate([[-1], writes]))[:-1]
+    p = np.maximum(prev, 0)
+    found = np.where((prev >= 0) & (slot[p] == slot), low[p] >> 1, -1)
+    out = np.empty(2 * m, np.int64)
+    out[low] = found                    # back to position 2t + kind
+    return out[1::2], out[0::2]
+
+
 def to_linkage_matrix(merges: np.ndarray, n: int | None = None) -> np.ndarray:
     """Convert slot-convention merges to a scipy-style linkage matrix ``Z``.
 
@@ -35,14 +89,14 @@ def to_linkage_matrix(merges: np.ndarray, n: int | None = None) -> np.ndarray:
     """
     merges = np.asarray(merges)
     n = _leaf_count(merges, n)
-    slot_id = np.arange(n)          # which cluster-id currently sits in a slot
     Z = np.zeros((merges.shape[0], 4))
-    for t in range(merges.shape[0]):
-        i, j, dist, size = merges[t]
-        i, j = int(round(i)), int(round(j))
-        a, b = slot_id[i], slot_id[j]
-        Z[t] = (min(a, b), max(a, b), dist, size)
-        slot_id[i] = n + t
+    i, j = _slot_indices(merges, n)
+    wi, wj = _slot_writers(i, j)
+    a = np.where(wi < 0, i, n + wi)     # the cluster id sitting in each slot
+    b = np.where(wj < 0, j, n + wj)
+    Z[:, 0] = np.minimum(a, b)
+    Z[:, 1] = np.maximum(a, b)
+    Z[:, 2:] = merges[:, 2:4]
     return Z
 
 
@@ -145,21 +199,57 @@ def canonical_order(
     for :func:`validate_merges` to reject after the sort.  Already
     height-sorted input (every LW engine's output) passes through
     unchanged.
+
+    The clamp runs as Jacobi rounds over the slot-writer index until no
+    height changes (children precede parents, so the fixed point is the
+    sequential scan's, bit for bit); a clamp chained deeper than
+    ``_CLAMP_ROUNDS`` falls back to the sequential scan.  The rounds taken
+    (``_CLAMP_ROUNDS + 1`` for the fallback) are observed into the
+    ``canonical_clamp_rounds`` histogram of the process registry.
     """
     merges = np.array(merges, copy=True)         # input dtype preserved
     n = _leaf_count(merges, n)
-    heights = merges[:, 2]
-    floor = np.zeros(n, heights.dtype)  # height of the slot's current cluster
-    for t in range(merges.shape[0]):
-        i, j = int(round(merges[t, 0])), int(round(merges[t, 1]))
-        need = max(floor[i], floor[j])
-        if heights[t] < need and heights[t] >= need - (atol + rtol * abs(need)):
-            heights[t] = need    # float noise, not a real inversion
-        floor[i] = heights[t]
-    order = np.argsort(heights, kind="stable")
+    wi, wj = _slot_writers(*_slot_indices(merges, n))
+    merges[:, 2], rounds = _clamp_heights(merges[:, 2], wi, wj, rtol, atol)
+    get_registry().histogram(
+        "canonical_clamp_rounds",
+        "rounds of canonical_order's height clamp per call",
+    ).observe(rounds)
+    order = np.argsort(merges[:, 2], kind="stable")
     out = merges[order]
     validate_merges(out, n=n)
     return out
+
+
+def _clamp_heights(
+    h0: np.ndarray, wi: np.ndarray, wj: np.ndarray, rtol: float, atol: float
+) -> tuple[np.ndarray, int]:
+    """:func:`canonical_order`'s dependency clamp and the rounds it took.
+
+    A merge's ``need`` is the larger height of the two clusters it
+    consumes (0 for a leaf); its height is lifted to ``need`` when it
+    falls below by at most ``atol + rtol·|need|``.  Each round recomputes
+    every merge from the previous round's heights; a round that changes
+    nothing ends it.
+    """
+    zero = np.zeros(1, h0.dtype)
+    h = h0
+    with np.errstate(over="ignore", invalid="ignore"):
+        for rounds in range(1, _CLAMP_ROUNDS + 1):
+            padded = np.concatenate([zero, h])
+            a, b = padded[wi + 1], padded[wj + 1]
+            need = np.where(b > a, b, a)                # max(a, b), a on ties
+            lift = (h0 < need) & (h0 >= need - (atol + rtol * np.abs(need)))
+            nxt = np.where(lift, need, h0)
+            if nxt.tobytes() == h.tobytes():
+                return h, rounds
+            h = nxt
+    padded = np.concatenate([zero, h0])
+    for t, (a, b) in enumerate(zip((wi + 1).tolist(), (wj + 1).tolist())):
+        need = max(padded[a], padded[b])
+        if padded[t + 1] < need and padded[t + 1] >= need - (atol + rtol * abs(need)):
+            padded[t + 1] = need    # float noise, not a real inversion
+    return padded[1:], _CLAMP_ROUNDS + 1
 
 
 def truncate_canonical(
@@ -382,23 +472,40 @@ def validate_merges(merges: np.ndarray, n: int | None = None) -> None:
     * each step merges two distinct live slots, ``i < j``
     * slot ``j`` never reappears after being tombstoned
     * sizes sum correctly (the final merge of a *full* run has size ``n``)
+
+    The first failing step raises, its checks taken in the order above
+    (a NaN recorded size fails).  Each check is one vectorized mask over
+    the steps: a step's mask reads the merges before it only through the
+    slot-writer index, and those merges passed, so a child's recorded
+    size rounds to its true size.
     """
     merges = np.asarray(merges)
     n = _leaf_count(merges, n)
-    alive = np.ones(n, bool)
-    sizes = np.ones(n)
-    for t in range(merges.shape[0]):
-        i, j = int(round(merges[t, 0])), int(round(merges[t, 1]))
+    m = merges.shape[0]
+    t = np.arange(m)
+    r = np.rint(merges[:, :2].astype(np.float64))
+    pair_ok = (0 <= r[:, 0]) & (r[:, 0] < r[:, 1]) & (r[:, 1] < n)
+    # past the first bad pair the index reads garbage, never before it
+    i, j = np.nan_to_num(r).clip(0, n - 1).astype(np.int64).T
+    death = np.full(n, m)             # first step that tombstones a slot
+    np.minimum.at(death, j, t)
+    dead = (death[i] < t) | (death[j] < t)
+    wi, wj = _slot_writers(i, j)
+    rounded = np.rint(merges[:, 3].astype(np.float64))
+    sizes = (np.where(wi < 0, 1.0, rounded[wi])
+             + np.where(wj < 0, 1.0, rounded[wj]))
+    size_bad = ~(np.abs(sizes - merges[:, 3]) <= 1e-3)
+    fail = ~pair_ok | dead | size_bad
+    if fail.any():
+        s = int(np.argmax(fail))
+        i, j = int(round(merges[s, 0])), int(round(merges[s, 1]))
         if not (0 <= i < j < n):
-            raise AssertionError(f"step {t}: bad slot pair ({i}, {j})")
-        if not (alive[i] and alive[j]):
-            raise AssertionError(f"step {t}: merging dead slot ({i}, {j})")
-        sizes[i] += sizes[j]
-        if abs(sizes[i] - merges[t, 3]) > 1e-3:
-            raise AssertionError(
-                f"step {t}: recorded size {merges[t, 3]} != {sizes[i]}"
-            )
-        alive[j] = False
-    if n > 1 and merges.shape[0] == n - 1:   # full run: one cluster remains
-        if abs(sizes[int(round(merges[-1, 0]))] - n) > 1e-3:
+            raise AssertionError(f"step {s}: bad slot pair ({i}, {j})")
+        if dead[s]:
+            raise AssertionError(f"step {s}: merging dead slot ({i}, {j})")
+        raise AssertionError(
+            f"step {s}: recorded size {merges[s, 3]} != {sizes[s]}"
+        )
+    if n > 1 and m == n - 1:   # full run: one cluster remains
+        if abs(sizes[-1] - n) > 1e-3:
             raise AssertionError("final cluster does not contain all items")

@@ -213,3 +213,30 @@ def test_phase_observes_into_the_registry_and_tracer_it_is_given():
     assert hist.count(phase="outer") == hist.count(phase="inner") == 1
     assert hist.sum(phase="inner") <= hist.sum(phase="outer")
     assert [(e.name, e.cat) for e in tracer.events()] == [("outer", "cluster")]
+
+
+@pytest.mark.parametrize("calls", [1, 3])
+def test_canonical_clamp_rounds_once_per_chain_call(calls):
+    """One ``canonical_clamp_rounds`` observation per chain ``cluster``
+    call, 1 on a tie-free tree (the first round lifts nothing); the LW
+    path never canonicalizes, so it observes none."""
+    reg = reset_registry()
+    cluster(_points(n=24), "complete", algorithm="lw")
+    assert reg.get("canonical_clamp_rounds") is None
+    for _ in range(calls):
+        cluster(_points(n=48, seed=4), "ward", matrix_free=True)
+    assert reg.get("canonical_clamp_rounds").window() == [1] * calls
+
+
+def test_canonical_clamp_rounds_reads_the_fallback():
+    """A clamp chained deeper than the cap takes the sequential scan and
+    reads cap + 1: a caterpillar whose heights fall, with rtol=1e30."""
+    from repro.core import dendrogram as dg
+
+    reg = reset_registry()
+    n = 4 * dg._CLAMP_ROUNDS
+    t = np.arange(n - 1)
+    merges = np.stack([0 * t, t + 1, n - t, t + 2], axis=1).astype(np.float64)
+    out = dg.canonical_order(merges, rtol=1e30)
+    assert (out[:, 2] == n).all()
+    assert reg.get("canonical_clamp_rounds").window() == [dg._CLAMP_ROUNDS + 1]
