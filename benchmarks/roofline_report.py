@@ -1,11 +1,9 @@
-"""Render the §Dry-run / §Roofline tables from results/dryrun.jsonl —
-plus measured roofline rows for the clustering hot kernel
+"""Roofline rows for the clustering hot kernel
 (:func:`repro.kernels.pairwise.row_sq_euclidean`), the one row-build
 every matrix-free chain step performs (DESIGN.md §11–12)."""
 
 from __future__ import annotations
 
-import json
 import os
 import sys
 
@@ -13,73 +11,6 @@ _ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 _SRC = os.path.join(_ROOT, "src")
 if _SRC not in sys.path:         # standalone `python benchmarks/...` use
     sys.path.insert(0, _SRC)
-
-
-
-def load(path: str = "results/dryrun.jsonl") -> dict:
-    cells = {}
-    if not os.path.exists(path):
-        return cells
-    with open(path) as f:
-        for line in f:
-            try:
-                r = json.loads(line)
-            except json.JSONDecodeError:
-                continue
-            cells[(r["arch"], r["shape"], r["mesh"])] = r
-    return cells
-
-
-def fmt_bytes(b):
-    if b is None:
-        return "-"
-    return f"{b / 2**30:.2f}"
-
-
-def roofline_table(cells: dict, mesh: str = "single") -> str:
-    """Markdown table: all three terms per (arch × shape), single-pod."""
-    out = ["| arch | shape | strat | compute_s | memory_s | collective_s | "
-           "dominant | bound_s | useful_ratio | temp_GiB |",
-           "|---|---|---|---|---|---|---|---|---|---|"]
-    for (arch, shape, m), r in sorted(cells.items()):
-        if m != mesh:
-            continue
-        if r["status"] == "skipped":
-            out.append(f"| {arch} | {shape} | - | - | - | - | SKIP | - | - | - |")
-            continue
-        if r["status"] != "ok":
-            out.append(f"| {arch} | {shape} | - | - | - | - | ERROR | - | - | - |")
-            continue
-        ro = r["roofline"]
-        bound = max(ro["compute_s"], ro["memory_s"], ro["collective_s"])
-        out.append(
-            f"| {arch} | {shape} | {r.get('strategy','-')} "
-            f"| {ro['compute_s']:.4f} | {ro['memory_s']:.4f} "
-            f"| {ro['collective_s']:.4f} | {ro['dominant']} | {bound:.4f} "
-            f"| {r['useful_flops_ratio']:.3f} "
-            f"| {fmt_bytes(r['memory_analysis'].get('temp_bytes'))} |")
-    return "\n".join(out)
-
-
-def dryrun_table(cells: dict) -> str:
-    out = ["| arch | shape | mesh | status | chips | compile_s | "
-           "args_GiB/dev | temp_GiB/dev | coll_GiB/dev |",
-           "|---|---|---|---|---|---|---|---|---|"]
-    for (arch, shape, m), r in sorted(cells.items()):
-        if r["status"] == "skipped":
-            out.append(f"| {arch} | {shape} | {m} | SKIP (no sub-quadratic "
-                       f"mechanism) | - | - | - | - | - |")
-            continue
-        if r["status"] != "ok":
-            out.append(f"| {arch} | {shape} | {m} | ERROR | - | - | - | - | - |")
-            continue
-        ma = r["memory_analysis"]
-        out.append(
-            f"| {arch} | {shape} | {m} | ok | {r['chips']} "
-            f"| {r['compile_s']:.0f} | {fmt_bytes(ma.get('argument_bytes'))} "
-            f"| {fmt_bytes(ma.get('temp_bytes'))} "
-            f"| {r['roofline']['coll_bytes_per_device'] / 2**30:.2f} |")
-    return "\n".join(out)
 
 
 def kernel_rows(n: int = 16384, d: int = 128) -> list[str]:
@@ -141,11 +72,6 @@ def kernel_rows(n: int = 16384, d: int = 128) -> list[str]:
 
 
 def main() -> None:
-    cells = load()
-    n_ok = sum(1 for r in cells.values() if r["status"] == "ok")
-    n_skip = sum(1 for r in cells.values() if r["status"] == "skipped")
-    print(f"dryrun_cells,{len(cells)},ok={n_ok} skip={n_skip}")
-    print(roofline_table(cells))
     print("name,us_per_call,derived")
     for row in kernel_rows():
         print(row)

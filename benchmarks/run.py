@@ -14,7 +14,7 @@ Paper artifact → bench mapping:
   batched multi-problem engine         → bench_batch (EXPERIMENTS.md §Batch)
   online serving layer (DESIGN.md §10) → bench_service (EXPERIMENTS.md §Service)
   overload sweep + gates (DESIGN.md §14) → bench_service.main_overload
-  (arch × shape) roofline table        → roofline_report (reads dryrun.jsonl)
+  row-build kernel roofline rows       → roofline_report
 
 Default sizes are CI-scale; pass --paper for the paper-scale n=1968 run.
 """

@@ -18,10 +18,11 @@ same unified merge loop (:mod:`repro.core.engine`):
   ``pallas_call`` batching rule prepends the batch grid dimension), see
   :func:`repro.kernels.ops.lance_williams_kernelized_batch`.
 
-Ragged batches are padded into **shape buckets** (the ``configs/shapes.py``
-idiom: a small static grid of shapes so compiles are amortized): problem
-``n`` is rounded up to the next bucket, the batch axis is rounded up to a
-power of two, and XLA's jit cache then guarantees one compile per
+Ragged batches are padded into **shape buckets** (:data:`BUCKETS`, the
+powers of two from 8 to 4096: a small static grid of shapes so compiles
+are amortized): problem ``n`` is rounded up to the next bucket, the
+batch axis is rounded up to a power of two, and XLA's jit cache then
+guarantees one compile per
 ``(bucket_n, bucket_B, method, engine, variant, compaction, algorithm)``
 for the lifetime of the process (a compacted run's whole stage schedule
 lives inside that one program).  Padded slots are born dead

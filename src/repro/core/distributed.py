@@ -87,7 +87,14 @@ from repro.core.nnchain import (
     summary_distance,
     summary_merge,
 )
+from repro.distributed.elastic import reshard_tree, validate_mesh_for_tree
 from repro.distributed.fault import SimulatedFailure, StepDeadline
+from repro.distributed.sharding import (
+    ParamSpec,
+    replicate,
+    shard_rows,
+    tree_shardings,
+)
 from repro.obs import NULL_TRACER, Tracer, get_registry, phase
 
 
@@ -552,8 +559,6 @@ _CHAIN_ROW_RULES = {"rows": (AXIS,)}
 
 def _chain_state_specs(n_pad: int, d_pad: int, n: int):
     """ParamSpec mirror of the sharded chain state tuple, in state order."""
-    from repro.models.common import ParamSpec
-
     rep = ParamSpec((n_pad,), (None,))
     scalar = ParamSpec((), ())
     return (
@@ -574,13 +579,10 @@ def _shrink_chain_state(state, fallback_mesh: Mesh, *, n_pad: int,
     """Validate + reshard the live chain state onto the fallback mesh.
 
     Validation runs BEFORE any state moves
-    (:func:`repro.checkpoint.elastic.validate_mesh_for_tree`), so an
+    (:func:`repro.distributed.elastic.validate_mesh_for_tree`), so an
     incompatible fallback fails with the offending leaves and axes named
     and the last consistent state still intact on the original mesh.
     """
-    from repro.checkpoint.elastic import reshard_tree, validate_mesh_for_tree
-    from repro.distributed.sharding import tree_shardings
-
     mesh2 = require_ring_mesh(fallback_mesh)
     p2 = int(mesh2.devices.size)
     specs = _chain_state_specs(n_pad, d_pad, n)
@@ -649,13 +651,13 @@ def distributed_nn_chain_from_points(
     A :class:`~repro.distributed.fault.StepDeadline` flags straggling
     segments (delayed shard) through ``log``/``RuntimeWarning``.
 
-    **Elastic shrink** (:mod:`repro.checkpoint.elastic`): with a
+    **Elastic shrink** (:mod:`repro.distributed.elastic`): with a
     ``fallback_mesh``, exhausting the restart budget does not kill the
     run — the sharded state is validated against the fallback
-    (:func:`~repro.checkpoint.elastic.validate_mesh_for_tree`; an
+    (:func:`~repro.distributed.elastic.validate_mesh_for_tree`; an
     incompatible mesh raises a ``RuntimeError`` naming the offending
     leaves and axes *before* any state moves), resharded onto it
-    (:func:`~repro.checkpoint.elastic.reshard_tree`), and the same
+    (:func:`~repro.distributed.elastic.reshard_tree`), and the same
     segment retried there with a fresh restart budget.  One shrink per
     run — a mesh that keeps failing has a problem restarts can't fix.
 
@@ -702,8 +704,6 @@ def distributed_nn_chain_from_points(
         bn = block_n
         n_pad = pad_to_mesh(n, p)
         d_pad = d
-    from repro.distributed.sharding import replicate, shard_rows
-
     with phase("sharded_chain/place"):
         if (n_pad, d_pad) != (n, d):
             X = jnp.pad(X, ((0, n_pad - n), (0, d_pad - d)))

@@ -1,13 +1,8 @@
-"""repro.data — deterministic synthetic pipelines (tokens, embeddings,
-conformations)."""
+"""repro.data — deterministic synthetic data (embeddings, conformations)."""
 
-from repro.data.pipeline import PipelineState, TokenPipeline
-from repro.data.synthetic import conformations, gaussian_mixture, token_batch
+from repro.data.synthetic import conformations, gaussian_mixture
 
 __all__ = [
-    "PipelineState",
-    "TokenPipeline",
     "conformations",
     "gaussian_mixture",
-    "token_batch",
 ]

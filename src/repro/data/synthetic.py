@@ -1,13 +1,9 @@
 """Deterministic synthetic data generators.
 
-Everything is a pure function of (seed, step) — the pipeline needs no
-stored state beyond the step counter, which makes checkpoint/resume exact
-and lets every host generate only its own shard (the data-parallel
-equivalent of the paper's "send each processor its portion").
+Each is a pure function of its seed, so a run, a test and its reference
+all see the same data.
 
 Generators:
-* token batches (zipf-ish LM stream with a repeated-ngram structure so the
-  loss actually falls during the example training runs)
 * gaussian-mixture embeddings (clusterable; ground-truth labels returned)
 * protein-like conformations (a base fold + per-cluster deformations) for
   the paper's RMSD pipeline
@@ -16,23 +12,6 @@ Generators:
 from __future__ import annotations
 
 import numpy as np
-
-
-def token_batch(seed: int, step: int, batch: int, seq_len: int,
-                vocab: int) -> dict:
-    """(batch, seq_len+1) int32 tokens → {tokens, labels} shifted pair."""
-    rng = np.random.default_rng((seed * 1_000_003 + step) % (2**63))
-    # zipf-ish marginal + short repeated motifs (learnable structure)
-    base = rng.zipf(1.3, size=(batch, seq_len + 1)).astype(np.int64)
-    toks = (base - 1) % vocab
-    motif = rng.integers(0, vocab, size=(batch, 8))
-    for b in range(0, batch, 2):               # half the rows carry motifs
-        pos = rng.integers(0, max(1, seq_len - 16))
-        reps = (seq_len + 1 - pos) // 8
-        if reps > 0:
-            toks[b, pos:pos + reps * 8] = np.tile(motif[b], reps)
-    toks = toks.astype(np.int32)
-    return {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
 
 
 def gaussian_mixture(seed: int, n: int, dim: int, k: int = 8,

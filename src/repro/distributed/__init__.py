@@ -1,21 +1,22 @@
-"""repro.distributed — sharding rules, collective helpers, fault simulation."""
+"""repro.distributed — state shardings, elastic resharding, fault simulation."""
 
+from repro.distributed.elastic import reshard_tree, validate_mesh_for_tree
 from repro.distributed.sharding import (
     AxisRules,
-    abstract_params,
-    logical_constraint,
-    make_rules,
-    sharding_scope,
+    ParamSpec,
+    replicate,
+    shard_rows,
     tree_pspecs,
     tree_shardings,
 )
 
 __all__ = [
     "AxisRules",
-    "abstract_params",
-    "logical_constraint",
-    "make_rules",
-    "sharding_scope",
+    "ParamSpec",
+    "replicate",
+    "reshard_tree",
+    "shard_rows",
     "tree_pspecs",
     "tree_shardings",
+    "validate_mesh_for_tree",
 ]

@@ -1,11 +1,9 @@
-"""Elastic scaling: reshard a checkpoint onto a different mesh.
+"""Elastic resharding: move a live state tree onto a different mesh.
 
-Checkpoints store full (unsharded) host arrays per leaf, so resharding is
-placement-only: restore with the *new* mesh's NamedShardings and the job
-continues on more/fewer chips — the elastic-scaling path for node loss or
-capacity changes.  ``reshard_tree`` also handles live trees (device→device
-via host) for in-job remeshing, and validates divisibility so a bad target
-mesh fails loudly before any state is touched.
+The sharded chain's fallback-mesh shrink (DESIGN.md §12) validates its
+state against the smaller mesh, so a bad target fails loudly before any
+state is touched, then places every leaf with the new mesh's shardings
+(device→device via the host).
 """
 
 from __future__ import annotations
@@ -19,15 +17,14 @@ from jax.sharding import Mesh
 
 def validate_mesh_for_tree(spec_tree, rules, mesh: Mesh) -> list[str]:
     """Return a list of leaves whose sharded dims don't divide on ``mesh``
-    (empty = mesh is valid for this parameter tree).
+    (empty = mesh is valid for this state tree).
 
     Maps each leaf's logical axes through ``rules`` directly rather than
     via ``tree_pspecs`` — the pspec mapping *silently replicates* a dim
-    that doesn't divide (the forgiving behavior training wants), which
-    is exactly the failure this validator exists to surface: a mesh
-    shrink that would quietly turn a sharded parameter into a replicated
-    one must fail loudly, naming the leaf, the offending logical axis
-    and the mesh axes it maps to.
+    that doesn't divide, which is exactly the failure this validator
+    exists to surface: a mesh shrink that would quietly turn a sharded
+    leaf into a replicated one must fail loudly, naming the leaf, the
+    offending logical axis and the mesh axes it maps to.
     """
     problems = []
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
@@ -64,21 +61,3 @@ def reshard_tree(tree: Any, shardings: Any) -> Any:
         return jax.device_put(host, sh)
 
     return jax.tree.map(one, tree, shardings)
-
-
-def restore_elastic(manager, step: int | None, like: Any, rules,
-                    new_mesh: Mesh, spec_tree=None):
-    """Restore a checkpoint onto ``new_mesh`` (any device count whose
-    shardings divide).  ``like`` gives the tree structure/dtypes."""
-    from repro.distributed.sharding import tree_shardings
-
-    if spec_tree is not None:
-        problems = validate_mesh_for_tree(spec_tree, rules, new_mesh)
-        if problems:
-            raise ValueError(
-                "target mesh incompatible with parameter tree:\n  "
-                + "\n  ".join(problems[:10]))
-        shardings = tree_shardings(spec_tree, rules, new_mesh)
-    else:
-        shardings = None
-    return manager.restore(step, like, shardings)

@@ -1,11 +1,10 @@
 """Fault-tolerance runtime pieces: failure injection, step deadlines
-(straggler mitigation), and the restartable step-loop driver.
+(straggler mitigation) and bounded retry.
 
 On real pods the failure signal comes from the runtime (missing heartbeat,
 ICI timeout, preemption notice); here those are *simulated* so the
-recovery machinery — resume-from-checkpoint, deadline skip, bounded retry
-— is real code under test, not a story.  ``run_resilient_loop`` is the
-driver ``launch/train.py`` uses.
+recovery machinery — the sharded chain's same-segment retry and fallback
+mesh, the service's bounded retry — is real code under test, not a story.
 
 Every fault event also lands on the process-global metrics registry
 (``fault_injected_failures_total`` / ``fault_deadline_exceeded_total``,
@@ -53,10 +52,9 @@ class StepDeadline:
     """Straggler watchdog: flags steps exceeding ``factor ×`` the median.
 
     On TPU pods a straggling host stalls the collective; the standard
-    mitigations are (a) alert + checkpoint-restart without the bad host
-    (elastic), (b) skip noncritical work (e.g. eval) until caught up.
-    This monitor produces the signal; the trainer logs and can trigger an
-    early checkpoint."""
+    mitigations are (a) alert + restart without the bad host (elastic),
+    (b) skip noncritical work until caught up.  This monitor produces the
+    signal; the sharded chain and the service worker log it."""
 
     factor: float = 3.0
     warmup: int = 5
@@ -148,53 +146,3 @@ def retry_call(
                 on_retry(attempt, exc)
             sleep(delay)
             attempt += 1
-
-
-def run_resilient_loop(
-    *,
-    start_step: int,
-    total_steps: int,
-    step_fn: Callable[[int], dict],
-    save_fn: Callable[[int], None],
-    restore_fn: Callable[[], int],
-    save_every: int = 50,
-    max_restarts: int = 3,
-    failure_plan: FailurePlan | None = None,
-    deadline: StepDeadline | None = None,
-    log: Callable[[str], None] = print,
-) -> int:
-    """Run steps with checkpoint/restart semantics.  Returns final step.
-
-    On failure: restore from the latest committed checkpoint and continue
-    (bounded by ``max_restarts``).  The data pipeline must be part of the
-    checkpointed state for exactness (it is — see PipelineState).
-    """
-    restarts = 0
-    step = start_step
-    while step < total_steps:
-        try:
-            t0 = time.perf_counter()
-            if failure_plan is not None:
-                failure_plan.check(step)
-            metrics = step_fn(step)
-            dt = time.perf_counter() - t0
-            if deadline is not None and deadline.observe(dt):
-                log(f"[fault] step {step}: straggler detected "
-                    f"({dt:.3f}s > {deadline.factor}× median) — "
-                    f"forcing early checkpoint")
-                save_fn(step)
-            if (step + 1) % save_every == 0 or step + 1 == total_steps:
-                save_fn(step + 1)
-            step += 1
-            if metrics and step % 10 == 0:
-                log(f"[train] step {step}: " + ", ".join(
-                    f"{k}={v:.4f}" for k, v in metrics.items()))
-        except SimulatedFailure as e:
-            restarts += 1
-            if restarts > max_restarts:
-                raise RuntimeError(
-                    f"exceeded max_restarts={max_restarts}") from e
-            log(f"[fault] {e} — restarting from latest checkpoint "
-                f"({restarts}/{max_restarts})")
-            step = restore_fn()
-    return step

@@ -1,29 +1,18 @@
-"""Logical-axis sharding rules (MaxText-style) for the model zoo.
+"""Declared shapes and shardings of the clustering engines' device state.
 
-Every parameter and activation in the models carries *logical* axis names
-(``'embed'``, ``'mlp'``, ``'heads'``, ``'seq'``, …).  A rule set maps the
-logical names onto physical mesh axes (``'pod'``, ``'data'``, ``'model'``)
-per sharding *strategy*:
-
-* ``tp``      — Megatron-style: attention heads + d_ff + vocab sharded over
-  ``model``; residual stream sequence-sharded (sequence parallelism);
-  parameters additionally FSDP-sharded over ``data`` (ZeRO-3).
-  Used when ``n_heads % model_axis == 0``.
-* ``fsdp_cp`` — context-parallel attention (q-sequence over ``model``) for
-  head counts that don't divide the axis; MLP stays d_ff-TP; attention
-  parameter storage fully sharded over (``data``, ``model``).
-
-The rules live in a context (set by the launcher / dry-run around trace
-time); models call :func:`logical_constraint` which becomes a no-op when no
-mesh is active (single-device smoke tests).
+A :class:`ParamSpec` names each dim of a state leaf with a *logical* axis
+(``"rows"`` for the sharded points/summary rows, ``None`` for a
+replicated dim); a rule set maps the logical names onto mesh axes.
+:func:`tree_shardings` turns a spec tree into ``NamedSharding``s for a
+mesh, and :mod:`repro.distributed.elastic` validates and reshards a live
+tree against them.  :func:`shard_rows` and :func:`replicate` place the
+arrays of the paper engines' data layout (DESIGN.md §12).
 """
 
 from __future__ import annotations
 
-import threading
-from contextlib import contextmanager
-from dataclasses import dataclass, field
-from typing import Any, Mapping, Sequence
+from dataclasses import dataclass
+from typing import Any, Mapping
 
 import jax
 import numpy as np
@@ -32,184 +21,20 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 AxisRules = Mapping[str, tuple[str, ...] | None]
 
 
-def make_rules(
-    strategy: str = "tp",
-    *,
-    multi_pod: bool = False,
-    long_context: bool = False,
-) -> dict[str, tuple[str, ...] | None]:
-    """Build the logical→physical axis map for a strategy.
+@dataclass(frozen=True)
+class ParamSpec:
+    """Declared state leaf: its shape and one logical axis per dim."""
 
-    ``long_context`` is the ``long_500k`` decode regime: batch==1, so the
-    ``data`` axis is redeployed to shard the KV/state sequence dimension.
-    """
-    if strategy not in ("tp", "fsdp_cp"):
-        raise ValueError(f"unknown sharding strategy {strategy!r}")
-    tp = strategy == "tp"
-    batch: tuple[str, ...] | None = ("pod", "data") if multi_pod else ("data",)
-    kv_seq: tuple[str, ...] | None = ("model",)
-    if long_context:
-        batch = None
-        kv_seq = ("pod", "data", "model") if multi_pod else ("data", "model")
+    shape: tuple[int, ...]
+    axes: tuple[str | None, ...]
 
-    rules: dict[str, tuple[str, ...] | None] = {
-        # ---- activations ---------------------------------------------------
-        "batch": batch,
-        # Sequence-sharded residual (Megatron-SP) for BOTH strategies.
-        # §Perf-1b tried a replicated residual for tp (classic Megatron
-        # all-reduces): collective fell 375→265 s but the memory term rose
-        # 310→422 s (every device re-touches full-seq activations at every
-        # pointwise op) — net WORSE; refuted and reverted.  The real
-        # baseline pathology was f32 boundary traffic (fixed by the
-        # bf16-cotangent cast, §Perf-1d).
-        "seq": ("model",),
-        "embed": None,
-        "heads": ("model",) if tp else None,
-        "q_seq": None if tp else ("model",),   # context-parallel q
-        "kv_heads": None,
-        "head_dim": None,
-        "mlp": ("model",),
-        "vocab": None,              # logits keep vocab unsharded (see models)
-        "kv_seq": kv_seq,           # decode-time cache sequence
-        "expert": None,
-        "state": None,
-        "layers": None,
-        "inner": ("model",),        # mamba d_inner / rwkv value channels
-        # ---- parameters (storage shardings; FSDP over data) ----------------
-        "p_embed": ("data",),
-        "p_embed_attn": ("data",) if tp else ("data", "model"),
-        "p_heads": ("model",) if tp else None,
-        "p_kv_heads": None,
-        "p_head_dim": None,
-        "p_mlp": ("model",),
-        "p_vocab": ("model",),
-        "p_layers": None,
-        "p_expert": None,
-        "p_expert_mlp": ("model",) if tp else None,
-        "p_inner": ("model",),      # mamba d_inner / rwkv value dim
-        "p_state": None,
-        "p_conv": None,
-        "p_none": None,
-    }
-    return rules
-
-
-@dataclass
-class _Scope(threading.local):
-    mesh: Mesh | None = None
-    rules: dict[str, Any] = field(default_factory=dict)
-
-
-_SCOPE = _Scope()
-
-
-@contextmanager
-def sharding_scope(mesh: Mesh | None, rules: AxisRules | None):
-    """Activate (mesh, rules) for constraints captured during tracing."""
-    prev = (_SCOPE.mesh, _SCOPE.rules)
-    _SCOPE.mesh, _SCOPE.rules = mesh, dict(rules or {})
-    try:
-        yield
-    finally:
-        _SCOPE.mesh, _SCOPE.rules = prev
-
-
-def _axes_to_pspec(axes: Sequence[str | None], rules: AxisRules, mesh: Mesh) -> P:
-    names = set(mesh.axis_names)
-    parts: list[Any] = []
-    used: set[str] = set()
-    for ax in axes:
-        if ax is None:
-            parts.append(None)
-            continue
-        phys = rules.get(ax)
-        if phys is None:
-            parts.append(None)
-            continue
-        # drop axes absent from the mesh (e.g. 'pod' on single-pod) and
-        # axes already consumed by an earlier dim (a mesh axis may shard
-        # only one tensor dim).
-        keep = tuple(p for p in phys if p in names and p not in used)
-        used.update(keep)
-        parts.append(keep if len(keep) > 1 else (keep[0] if keep else None))
-    return P(*parts)
-
-
-def logical_constraint(x: jax.Array, *axes: str | None) -> jax.Array:
-    """Constrain ``x``'s sharding by logical axis names (no-op w/o scope)."""
-    mesh, rules = _SCOPE.mesh, _SCOPE.rules
-    if mesh is None or not rules:
-        return x
-    if len(axes) != x.ndim:
-        raise ValueError(f"{len(axes)} axes for rank-{x.ndim} array")
-    spec = _axes_to_pspec(axes, rules, mesh)
-    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
-
-
-def current_mesh() -> Mesh | None:
-    return _SCOPE.mesh
-
-
-def current_rules() -> dict[str, Any]:
-    return _SCOPE.rules
-
-
-def reshard_for_compute(layer_params, layer_specs, *, skip: tuple = ()):
-    """§Perf-1: constrain per-layer weights to their COMPUTE sharding —
-    TP (`model`) kept, FSDP storage axes (`data`/`pod`) gathered — *inside*
-    the scan body.
-
-    The gather source is the per-iteration dynamic slice of the stacked
-    weights, so XLA cannot hoist it out of the loop (the baseline
-    pathology: loop-invariant full-stack all-gathers, temp ≫ HBM) and
-    cannot fall back to contraction-sharded partial matmuls (the baseline's
-    huge activation all-reduces).  One clean (d, f/model) all-gather per
-    weight per layer per pass instead.
-    """
-    mesh, rules = _SCOPE.mesh, _SCOPE.rules
-    if mesh is None or not rules:
-        return layer_params
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    from repro.models.common import ParamSpec
-
-    def one(leaf, spec):
-        if not isinstance(spec, ParamSpec):
-            return leaf
-        parts: list[Any] = []
-        used: set[str] = set()
-        for dim, ax in zip(spec.shape, spec.axes):
-            phys = rules.get(ax) if ax else None
-            keep = tuple(p for p in (phys or ())
-                         if p == "model" and p in sizes and p not in used)
-            total = 1
-            for p in keep:
-                total *= sizes[p]
-            if keep and dim % total == 0:
-                used.update(keep)
-                parts.append(keep if len(keep) > 1 else keep[0])
-            else:
-                parts.append(None)
-        return jax.lax.with_sharding_constraint(
-            leaf, NamedSharding(mesh, P(*parts)))
-
-    def walk(p_tree, s_tree):
-        if isinstance(p_tree, dict):
-            return {k: (p_tree[k] if k in skip else
-                        walk(p_tree[k], s_tree[k]))
-                    for k in p_tree}
-        return one(p_tree, s_tree)
-
-    return walk(layer_params, layer_specs)
-
-
-# ---------------------------------------------------------------------------
-# parameter trees
-# ---------------------------------------------------------------------------
+    def __post_init__(self):
+        if len(self.shape) != len(self.axes):
+            raise ValueError(f"shape {self.shape} vs axes {self.axes}")
 
 
 def tree_pspecs(spec_tree, rules: AxisRules, mesh: Mesh):
     """Map a tree of ParamSpec (anything with ``.axes``) to PartitionSpecs."""
-    from repro.models.common import ParamSpec  # local import to avoid cycle
 
     def one(spec):
         if isinstance(spec, ParamSpec):
@@ -243,17 +68,6 @@ def tree_shardings(spec_tree, rules: AxisRules, mesh: Mesh):
         tree_pspecs(spec_tree, rules, mesh),
         is_leaf=lambda s: isinstance(s, P),
     )
-
-
-def abstract_params(spec_tree, dtype_default=None):
-    """ParamSpec tree → ShapeDtypeStruct tree (for .lower / eval_shape)."""
-    import jax.numpy as jnp
-
-    def one(spec):
-        dt = spec.dtype or dtype_default or jnp.float32
-        return jax.ShapeDtypeStruct(spec.shape, dt)
-
-    return jax.tree.map(one, spec_tree, is_leaf=lambda s: hasattr(s, "axes"))
 
 
 # ---------------------------------------------------------------------------

@@ -63,7 +63,7 @@ def main() -> None:
         if len(members):
             purity += np.bincount(members).max()
     purity /= len(truth)
-    print(f"[cluster] {res.n - 1} merges in {t_cluster:.2f}s "
+    print(f"[cluster] {res.n_merges} merges in {t_cluster:.2f}s "
           f"(backend={res.backend}); purity@k={args.k}: {purity:.3f}")
     heights = res.heights()
     print(f"[cluster] merge heights: min={heights.min():.3f} "

@@ -1,5 +1,2 @@
-"""repro.roofline — three-term roofline from compiled dry-run artifacts."""
-
-from repro.roofline.analysis import Roofline, analyze, collective_bytes, model_flops
-
-__all__ = ["Roofline", "analyze", "collective_bytes", "model_flops"]
+"""repro.roofline — loop-aware HLO cost model (``hlo_cost``) and the
+TPU v5e peaks it is read against (``hw``)."""

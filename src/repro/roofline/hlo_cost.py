@@ -1,11 +1,10 @@
 """Loop-aware cost model over compiled HLO text.
 
 XLA's ``HloCostAnalysis`` (what ``compiled.cost_analysis()`` reports) counts
-every computation ONCE — a ``lax.scan`` over 126 layers contributes a
-single layer's FLOPs.  Since this framework scans everything (layers,
-microbatches, attention chunks), those numbers undercount by orders of
-magnitude.  This module re-derives loop-aware totals from the optimized
-HLO text itself:
+every computation ONCE — a ``while`` loop over n − 1 merges contributes a
+single merge's FLOPs.  Since every engine here is such a loop, those
+numbers undercount by orders of magnitude.  This module re-derives
+loop-aware totals from the optimized HLO text itself:
 
 * ``while`` ops carry ``backend_config={"known_trip_count":{"n":...}}`` —
   body/condition costs are multiplied through (nested loops compose);
@@ -17,8 +16,8 @@ HLO text itself:
 * collective wire bytes use a ring model: all-gather ≈ out·(g−1)/g,
   all-reduce ≈ 2·out·(g−1)/g, reduce-scatter ≈ out·(g−1), all-to-all ≈
   out·(g−1)/g, collective-permute ≈ out — with the replica-group size g
-  parsed per op, and loop multipliers applied (a per-layer all-gather in
-  a 126-layer scan counts 126×).
+  parsed per op, and loop multipliers applied (a per-trip all-reduce in
+  a 126-trip loop counts 126×).
 
 Everything here is per-DEVICE (the module is the SPMD-partitioned one).
 """

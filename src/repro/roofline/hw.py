@@ -2,9 +2,6 @@
 
 PEAK_FLOPS_BF16 = 197e12       # per chip, bf16
 HBM_BW = 819e9                 # bytes/s per chip
-ICI_BW = 50e9                  # bytes/s per link (~ per chip, ring)
-CHIPS_PER_POD = 256
-HBM_BYTES = 16 * 2**30         # 16 GiB per chip
 
 DTYPE_BYTES = {
     "f64": 8, "f32": 4, "f16": 2, "bf16": 2,

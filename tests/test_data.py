@@ -1,38 +1,9 @@
-"""Data pipeline: determinism, exact resume, clusterable generators."""
+"""Synthetic data: determinism and clusterable generators."""
 
 import numpy as np
 import pytest
 
-from repro.data.pipeline import PipelineState, TokenPipeline
-from repro.data.synthetic import conformations, gaussian_mixture, token_batch
-
-
-def test_token_batch_deterministic():
-    a = token_batch(7, 3, 4, 16, 1000)
-    b = token_batch(7, 3, 4, 16, 1000)
-    np.testing.assert_array_equal(a["tokens"], b["tokens"])
-    c = token_batch(7, 4, 4, 16, 1000)
-    assert not np.array_equal(a["tokens"], c["tokens"])
-    assert a["tokens"].max() < 1000 and a["tokens"].min() >= 0
-    # labels are next-token shifted
-    full = token_batch(7, 3, 4, 16, 1000)
-    np.testing.assert_array_equal(full["tokens"][:, 1:], full["labels"][:, :-1])
-
-
-def test_pipeline_resume_exact():
-    p1 = TokenPipeline(vocab=500, batch=4, seq_len=8, seed=1)
-    seen = [np.asarray(p1.next()["tokens"]) for _ in range(5)]
-    p1.close()
-    # resume from step 3
-    p2 = TokenPipeline(vocab=500, batch=4, seq_len=8, seed=1, start_step=3)
-    b3 = np.asarray(p2.next()["tokens"])
-    p2.close()
-    np.testing.assert_array_equal(b3, seen[3])
-
-
-def test_pipeline_state_serializable():
-    s = PipelineState(seed=2, step=17)
-    assert PipelineState.from_dict(s.to_dict()) == s
+from repro.data.synthetic import conformations, gaussian_mixture
 
 
 def test_gaussian_mixture_separable():

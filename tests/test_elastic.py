@@ -1,4 +1,4 @@
-"""Elastic resharding (checkpoint/elastic.py) + the chain's fallback-mesh
+"""Elastic resharding (distributed/elastic.py) + the chain's fallback-mesh
 shrink path (DESIGN.md §12 / §14 robustness satellite).
 
 ``validate_mesh_for_tree`` must report *every* leaf whose sharded dims
@@ -24,7 +24,7 @@ from tests.conftest import run_with_devices
 
 
 def test_reshard_tree_none_shardings_is_identity():
-    from repro.checkpoint.elastic import reshard_tree
+    from repro.distributed.elastic import reshard_tree
 
     tree = {"a": np.arange(6.0), "b": (np.ones((2, 3)), 7)}
     out = reshard_tree(tree, {"a": None, "b": (None, None)})
@@ -36,8 +36,8 @@ def test_validate_trivial_mesh_always_divides():
     import jax
     from jax.sharding import Mesh
 
-    from repro.checkpoint.elastic import validate_mesh_for_tree
-    from repro.models.common import ParamSpec
+    from repro.distributed.elastic import validate_mesh_for_tree
+    from repro.distributed.sharding import ParamSpec
 
     mesh = Mesh(np.array(jax.devices()[:1]), ("p",))
     spec = {"W": ParamSpec((13, 7), ("rows", None))}
@@ -52,8 +52,8 @@ def test_validate_mesh_reports_offending_leaves_and_axes():
     run_with_devices("""
 import numpy as np, jax
 from jax.sharding import Mesh
-from repro.checkpoint.elastic import validate_mesh_for_tree
-from repro.models.common import ParamSpec
+from repro.distributed.elastic import validate_mesh_for_tree
+from repro.distributed.sharding import ParamSpec
 
 mesh3 = Mesh(np.array(jax.devices()[:3]), ("p",))
 rules = {"rows": ("p",)}
@@ -87,7 +87,7 @@ def test_reshard_tree_moves_values_across_meshes():
     run_with_devices("""
 import numpy as np, jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from repro.checkpoint.elastic import reshard_tree
+from repro.distributed.elastic import reshard_tree
 
 devs = jax.devices()
 mesh4 = Mesh(np.array(devs[:4]), ("p",))
@@ -100,43 +100,6 @@ assert moved.sharding.mesh.devices.size == 2
 # each of the 2 shards holds 4 rows now
 shard_shapes = {s.data.shape for s in moved.addressable_shards}
 assert shard_shapes == {(4, 3)}, shard_shapes
-print("OK")
-""", n_devices=4)
-
-
-@pytest.mark.slow
-def test_restore_elastic_validates_before_touching_state():
-    run_with_devices("""
-import numpy as np, jax
-from jax.sharding import Mesh
-from repro.checkpoint.elastic import restore_elastic
-from repro.models.common import ParamSpec
-
-class Manager:
-    calls = []
-    def restore(self, step, like, shardings):
-        self.calls.append((step, shardings))
-        return like
-
-spec = {"W": ParamSpec((10, 4), ("rows", None))}
-rules = {"rows": ("p",)}
-like = {"W": np.zeros((10, 4), np.float32)}
-mgr = Manager()
-
-# incompatible mesh: typed failure naming the leaf, manager untouched
-mesh3 = Mesh(np.array(jax.devices()[:3]), ("p",))
-try:
-    restore_elastic(mgr, 0, like, rules, mesh3, spec_tree=spec)
-    raise AssertionError("expected ValueError")
-except ValueError as e:
-    assert "W" in str(e) and "rows" in str(e), e
-assert mgr.calls == []
-
-# compatible mesh: restores with the new mesh's shardings
-mesh2 = Mesh(np.array(jax.devices()[:2]), ("p",))
-restore_elastic(mgr, 0, like, rules, mesh2, spec_tree=spec)
-(step, shardings), = mgr.calls
-assert shardings["W"].mesh.devices.size == 2
 print("OK")
 """, n_devices=4)
 

@@ -4,7 +4,6 @@ import jax
 import jax.numpy as jnp
 import pytest
 
-from repro.roofline.analysis import Roofline
 from repro.roofline.hlo_cost import HloCost, _wire_bytes
 
 
@@ -70,17 +69,6 @@ def test_wire_byte_ring_model():
     assert _wire_bytes("all-to-all", 1000, 4) == 750
     assert _wire_bytes("collective-permute", 1000, 4) == 1000
     assert _wire_bytes("all-reduce", 1000, 1) == 0
-
-
-def test_roofline_terms_and_dominant():
-    r = Roofline(flops_per_device=197e12, bytes_per_device=819e9,
-                 coll_bytes_per_device=100e9, chips=256)
-    assert abs(r.compute_s - 1.0) < 1e-9
-    assert abs(r.memory_s - 1.0) < 1e-9
-    assert abs(r.collective_s - 2.0) < 1e-9
-    assert r.dominant == "collective"
-    d = r.to_dict()
-    assert d["dominant"] == "collective"
 
 
 def test_collectives_parsed_from_sharded_module():

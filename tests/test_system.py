@@ -1,15 +1,13 @@
 """End-to-end behaviour tests for the paper's system.
 
 The top-level contract: raw objects in → correct hierarchy out, on every
-backend; plus the serving path and the dry-run driver on reduced configs.
+backend (tests/test_scipy_conformance.py checks every route against scipy).
 """
 
 import numpy as np
-import pytest
 
 from repro.core import cluster
 from repro.data.synthetic import conformations, gaussian_mixture
-from tests.conftest import run_with_devices
 
 
 def _purity(labels, truth, k):
@@ -39,47 +37,3 @@ def test_protein_pipeline_end_to_end():
     C, y = conformations(0, 36, 16, k=3, noise=0.05)
     res = cluster(C, method="complete", metric="rmsd", backend="serial")
     assert _purity(res.labels(3), y, 3) > 0.9
-
-
-def test_all_methods_run_via_api():
-    X, _ = gaussian_mixture(2, 40, 5, k=3)
-    for method in ("single", "complete", "average", "weighted",
-                   "centroid", "median", "ward"):
-        res = cluster(X, method=method, backend="serial")
-        assert res.merges.shape == (39, 4), method
-
-
-@pytest.mark.slow
-def test_dryrun_driver_reduced_cell():
-    """The dry-run machinery itself (production 16×16 mesh, reduced dims)."""
-    run_with_devices("""
-import os
-os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
-import sys
-sys.argv = ["dryrun", "--arch", "chatglm3-6b", "--shape", "train_4k",
-            "--mesh", "single", "--reduced", "--out", "/tmp/dr_test.jsonl"]
-import runpy
-try:
-    runpy.run_module("repro.launch.dryrun", run_name="__main__")
-except SystemExit as e:
-    assert e.code in (0, None), e.code
-import json
-rec = [json.loads(line) for line in open("/tmp/dr_test.jsonl")][-1]
-assert rec["status"] == "ok", rec
-assert rec["chips"] == 256
-assert rec["roofline"]["flops_per_device"] > 0
-assert rec["roofline"]["coll_bytes_per_device"] > 0
-print("OK")
-""", n_devices=1, timeout=560)
-
-
-@pytest.mark.slow
-def test_serve_driver_reduced():
-    run_with_devices("""
-import sys
-sys.argv = ["serve", "--arch", "chatglm3-6b", "--reduced", "--requests", "4",
-            "--batch", "2", "--prompt-len", "8", "--max-new", "4"]
-import runpy
-runpy.run_module("repro.launch.serve", run_name="__main__")
-print("OK")
-""", n_devices=4)
